@@ -163,7 +163,7 @@ def _make_backend(name: str, cfg: SchedulerConfig, spec: bool):
     from repro.backend.jax_backend import JaxBackend
     kw = dict(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
               num_swap_blocks=cfg.num_swap_blocks,
-              copy_streams=cfg.copy_streams, vocab=128, interpret=True)
+              copy_streams=cfg.copy_streams, vocab=128)
     dev = DeviceModel(t_fixed=1e-5, t_prefill_tok=1e-8, t_decode_seq=1e-6)
     if name == "emulated":
         target = EmulatedBackend(dev)

@@ -30,16 +30,19 @@ def __getattr__(name):
     raise AttributeError(name)
 
 
-def _physical_leaf(name: str, cfg, kv_dtype: str = "float32"):
+def _physical_leaf(name: str, cfg, kv_dtype: str = "float32", model=None):
     if name == "jax":
         from repro.backend.jax_backend import JaxBackend
         cls = JaxBackend
     else:
         from repro.backend.cpu_decode import CpuDecodeBackend
         cls = CpuDecodeBackend
+    widths = {} if model is None else dict(
+        n_heads=model.n_heads, n_kv_heads=model.n_kv_heads,
+        head_dim=model.head_dim, vocab=model.vocab_size)
     return cls(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
                num_swap_blocks=cfg.num_swap_blocks,
-               copy_streams=cfg.copy_streams, kv_dtype=kv_dtype)
+               copy_streams=cfg.copy_streams, kv_dtype=kv_dtype, **widths)
 
 
 def make_backend(name: str, *, device=None, scheduler_cfg=None,
@@ -49,7 +52,8 @@ def make_backend(name: str, *, device=None, scheduler_cfg=None,
                  kv_dtype: str = "float32",
                  draft_backend: str = "",
                  draft_slowdown: float = 8.0,
-                 spec_accept_rate=None):
+                 spec_accept_rate=None,
+                 model=None):
     """Build a backend by name (one of ``BACKEND_NAMES``).
 
     ``device`` feeds the emulated sleep model; ``scheduler_cfg`` sizes the
@@ -75,7 +79,11 @@ def make_backend(name: str, *, device=None, scheduler_cfg=None,
     otherwise — an emulated draft costs ``cpu_tier(draft_slowdown)`` and
     models acceptance with ``spec_accept_rate``).  The draft's pool is
     always fp32: it is the cheap CPU tier, and its candidates are only
-    hints — the verify pass prices the int8 savings."""
+    hints — the verify pass prices the int8 savings.
+
+    ``model`` (a ``repro.configs.ModelConfig``) gives the physical
+    backends' surrogate its heads, kv heads, head dim and vocabulary;
+    without it they keep their small default widths."""
     import dataclasses
 
     from repro.core.devmodel import DeviceModel
@@ -93,7 +101,7 @@ def make_backend(name: str, *, device=None, scheduler_cfg=None,
     if name == "emulated":
         base = EmulatedBackend(device.with_kv_dtype(kv_dtype))
     elif name in physical:
-        base = _physical_leaf(name, cfg, kv_dtype)
+        base = _physical_leaf(name, cfg, kv_dtype, model)
     elif name == "hybrid":
         from repro.backend.hybrid import HybridBackend
         if "hybrid" in (prefill_backend, decode_backend):
@@ -117,7 +125,7 @@ def make_backend(name: str, *, device=None, scheduler_cfg=None,
                        .with_kv_dtype(tier_dtype)
                        if role == "decode" else device)
                 return EmulatedBackend(dev)
-            return _physical_leaf(child_name, cfg, tier_dtype)
+            return _physical_leaf(child_name, cfg, tier_dtype, model)
 
         base = HybridBackend(
             child(prefill_backend, "prefill"),
@@ -150,5 +158,5 @@ def make_backend(name: str, *, device=None, scheduler_cfg=None,
         draft = EmulatedBackend(
             device.cpu_tier(decode_slowdown=draft_slowdown))
     else:
-        draft = _physical_leaf(dname, cfg)          # fp32 draft pool
+        draft = _physical_leaf(dname, cfg, model=model)   # fp32 draft pool
     return SpeculativeBackend(draft, base, accept_rate=spec_accept_rate)

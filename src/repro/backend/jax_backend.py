@@ -5,8 +5,8 @@ The accelerator-class physical backend: the shared paged surrogate
 pool addressed through the scheduler's block tables, a host pool for
 swap traffic, contract-ordered directive application — and this class
 supplies the execution engine: every step runs the
-``kernels/paged_decode_attention`` pallas kernel (interpret mode on this
-CPU-only container) over exactly the pages the batch references.
+``kernels/paged_decode_attention`` pallas kernel (compiled on a TPU, in
+the interpreter on the CPU) over exactly the pages the batch references.
 Prefill chunks write their K/V into the request's pages; shared prefix
 pages are written once and attended by every request that locks them.
 
@@ -15,22 +15,74 @@ per-step batch really is assembled from the plan, the gather really is
 block-indexed — while staying cheap enough for unit tests.  Sampling is
 greedy argmax, deterministic given the seed, so the conformance contract
 (same plan sequence -> same completion order and token counts) is exact.
+
+The two jitted device programs, ``attend_logits`` and ``decode_scan``,
+are module functions so they can be compiled on their own; jit keys their
+executables by the power-of-2 bucketed shapes the backend pads to.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.backend.surrogate import PagedSurrogateBackend, _pow2_at_least
+from repro.kernels.paged_decode_attention import paged_decode_attention
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def attend_logits(q, k_pages, v_pages, tables, seq_lens, wo, k_scales=None,
+                  v_scales=None, *, interpret=None):
+    """One batched decode-attention step: the paged kernel, then the
+    output projection to logits [rows, vocab]."""
+    out = paged_decode_attention(q, k_pages, v_pages, tables, seq_lens,
+                                 k_scales=k_scales, v_scales=v_scales,
+                                 interpret=interpret)
+    return out.reshape(out.shape[0], -1) @ wo
+
+
+@functools.partial(jax.jit, static_argnames=("n_steps", "interpret"))
+def decode_scan(kc, vc, bt, sl0, tok0, bud, eos_v, embed, wq, wk, wv, wo, *,
+                n_steps, interpret=None):
+    """``n_steps`` greedy decode iterations as one ``lax.scan`` over a
+    compact page pool [KV, pool, block, D] whose last page is scratch:
+    rows past their budget or EOS write there and emit nothing."""
+    kv_heads, pool, bs, d = kc.shape
+    vocab = embed.shape[0]
+    n_heads = wq.shape[1] // d
+    scratch = pool - 1
+
+    def body(carry, s):
+        kc, vc, tok, alive = carry
+        emit = alive & (s < bud)
+        e = embed[tok % vocab]                            # [rows_p, E]
+        pos = sl0 + s          # valid while emitting: emission is
+                               # prefix-contiguous from s=0
+        kn = (e @ wk).reshape(-1, kv_heads, d)
+        vn = (e @ wv).reshape(-1, kv_heads, d)
+        page = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
+        page = jnp.where(emit, page, scratch)
+        slot = pos % bs
+        kc = kc.at[:, page, slot].set(jnp.swapaxes(kn, 0, 1))
+        vc = vc.at[:, page, slot].set(jnp.swapaxes(vn, 0, 1))
+        q = (e @ wq).reshape(-1, n_heads, d)
+        sl = jnp.where(emit, pos + 1, 0)
+        out = paged_decode_attention(q, kc, vc, bt, sl, interpret=interpret)
+        logits = out.reshape(out.shape[0], -1) @ wo
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        alive = emit & (nxt != eos_v)
+        return (kc, vc, nxt, alive), (nxt, emit)
+
+    init = (kc, vc, tok0, jnp.ones_like(tok0, dtype=bool))
+    (kc, vc, _, _), (toks, emits) = jax.lax.scan(body, init,
+                                                 jnp.arange(n_steps))
+    return kc, vc, toks, emits
 
 
 class JaxBackend(PagedSurrogateBackend):
-
-    def __init__(self, **kwargs):
-        super().__init__(**kwargs)
-        self._attend_cache: Dict = {}
-        self._scan_cache: Dict = {}
 
     def _attend(self, q: np.ndarray, tables: np.ndarray,
                 seq_lens: np.ndarray) -> np.ndarray:
@@ -41,11 +93,6 @@ class JaxBackend(PagedSurrogateBackend):
         cost scales with batch x context, not with the whole pool.  Shapes
         are padded to power-of-2 buckets so the jitted pallas call
         compiles once per bucket, not once per batch composition."""
-        import jax
-        import jax.numpy as jnp
-
-        from repro.kernels.paged_decode_attention import paged_decode_attention
-
         rows = q.shape[0]
         used = np.unique(tables[tables >= 0])
         remap = np.full(self.num_blocks, -1, np.int32)
@@ -56,27 +103,6 @@ class JaxBackend(PagedSurrogateBackend):
         nb_p = _pow2_at_least(max(tables.shape[1], 1), 2)
         pool_p = _pow2_at_least(max(len(used), 1), 2)
         quant = self.kv_dtype == "int8"
-        key = (rows_p, nb_p, pool_p)
-        if key not in self._attend_cache:
-            interpret = self.interpret
-
-            if quant:
-                @jax.jit
-                def run(qp, kp, vp, bt, sl, ks, vs, wo):
-                    out = paged_decode_attention(qp, kp, vp, bt, sl,
-                                                 k_scales=ks, v_scales=vs,
-                                                 interpret=interpret)
-                    flat = out.reshape(out.shape[0], -1)
-                    return flat @ wo
-            else:
-                @jax.jit
-                def run(qp, kp, vp, bt, sl, wo):
-                    out = paged_decode_attention(qp, kp, vp, bt, sl,
-                                                 interpret=interpret)
-                    flat = out.reshape(out.shape[0], -1)
-                    return flat @ wo
-
-            self._attend_cache[key] = run
         qp = np.zeros((rows_p, self.n_heads, self.head_dim), np.float32)
         qp[:rows] = q
         bt = np.full((rows_p, nb_p), -1, np.int32)
@@ -89,6 +115,7 @@ class JaxBackend(PagedSurrogateBackend):
         vc = np.zeros_like(kc)
         kc[:, :len(used)] = self.k_pages[:, used]
         vc[:, :len(used)] = self.v_pages[:, used]
+        scales = {}
         if quant:
             # ship int8 codes + per-page scales; the kernel dequantizes
             # on load, so HBM->VMEM traffic is the halved-byte pool
@@ -96,14 +123,10 @@ class JaxBackend(PagedSurrogateBackend):
             vs = np.zeros_like(ks)
             ks[:, :len(used)] = self.k_scales[:, used]
             vs[:, :len(used)] = self.v_scales[:, used]
-            logits = self._attend_cache[key](
-                jnp.asarray(qp), jnp.asarray(kc), jnp.asarray(vc),
-                jnp.asarray(bt), jnp.asarray(sl), jnp.asarray(ks),
-                jnp.asarray(vs), jnp.asarray(self._wo))
-        else:
-            logits = self._attend_cache[key](
-                jnp.asarray(qp), jnp.asarray(kc), jnp.asarray(vc),
-                jnp.asarray(bt), jnp.asarray(sl), jnp.asarray(self._wo))
+            scales = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+        logits = attend_logits(
+            jnp.asarray(qp), jnp.asarray(kc), jnp.asarray(vc),
+            jnp.asarray(bt), jnp.asarray(sl), jnp.asarray(self._wo), **scales)
         return np.asarray(logits)[:rows]
 
     # -- fused multi-step decode (docs/multi_step.md) -------------------
@@ -132,11 +155,6 @@ class JaxBackend(PagedSurrogateBackend):
             # attends through the dequant-on-load kernel path.
             return super()._decode_multi(rids, tables, start, first,
                                          budgets, eos, k)
-        import jax
-        import jax.numpy as jnp
-
-        from repro.kernels.paged_decode_attention import paged_decode_attention
-
         rows = len(rids)
         nb_max = max(max(len(tables[rid]) for rid in rids), 1)
         tb = np.full((rows, nb_max), -1, np.int32)
@@ -149,9 +167,9 @@ class JaxBackend(PagedSurrogateBackend):
 
         rows_p = _pow2_at_least(rows, 2)
         nb_p = _pow2_at_least(nb_max, 2)
-        # one scratch page past the gathered set: masked rows write there
+        # the compact pool's last page, past the gathered set, is scratch:
+        # masked rows write there (decode_scan)
         pool_p = _pow2_at_least(len(used) + 1, 2)
-        scratch = len(used)
 
         bt = np.full((rows_p, nb_p), -1, np.int32)
         bt[:rows, :nb_max] = compact
@@ -169,52 +187,12 @@ class JaxBackend(PagedSurrogateBackend):
         kc[:, :len(used)] = self.k_pages[:, used]
         vc[:, :len(used)] = self.v_pages[:, used]
 
-        key = (rows_p, nb_p, pool_p, k)
-        if key not in self._scan_cache:
-            bs = self.block_size
-            H, KV = self.n_heads, self.n_kv_heads
-            D, vocab = self.head_dim, self.vocab
-            interpret = self.interpret
-
-            @jax.jit
-            def run(kc, vc, bt, sl0, tok0, bud, eos_v,
-                    embed, wq, wk, wv, wo):
-                def body(carry, s):
-                    kc, vc, tok, alive = carry
-                    emit = alive & (s < bud)
-                    e = embed[tok % vocab]                    # [rows_p, E]
-                    pos = sl0 + s          # valid while emitting: emission
-                                           # is prefix-contiguous from s=0
-                    kn = (e @ wk).reshape(-1, KV, D)
-                    vn = (e @ wv).reshape(-1, KV, D)
-                    page = jnp.take_along_axis(
-                        bt, (pos // bs)[:, None], axis=1)[:, 0]
-                    page = jnp.where(emit, page, scratch)
-                    slot = pos % bs
-                    kc = kc.at[:, page, slot].set(jnp.swapaxes(kn, 0, 1))
-                    vc = vc.at[:, page, slot].set(jnp.swapaxes(vn, 0, 1))
-                    q = (e @ wq).reshape(-1, H, D)
-                    sl = jnp.where(emit, pos + 1, 0)
-                    out = paged_decode_attention(q, kc, vc, bt, sl,
-                                                 interpret=interpret)
-                    logits = out.reshape(out.shape[0], -1) @ wo
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    alive = emit & (nxt != eos_v)
-                    return (kc, vc, nxt, alive), (nxt, emit)
-
-                init = (kc, vc, tok0, jnp.ones_like(tok0, dtype=bool))
-                (kc, vc, _, _), (toks, emits) = jax.lax.scan(
-                    body, init, jnp.arange(k))
-                return kc, vc, toks, emits
-
-            self._scan_cache[key] = run
-
-        kc_o, vc_o, toks, emits = self._scan_cache[key](
+        kc_o, vc_o, toks, emits = decode_scan(
             jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(bt),
             jnp.asarray(sl0), jnp.asarray(tok0), jnp.asarray(bud),
             jnp.asarray(eos_v), jnp.asarray(self._embed),
             jnp.asarray(self._wq), jnp.asarray(self._wk),
-            jnp.asarray(self._wv), jnp.asarray(self._wo))
+            jnp.asarray(self._wv), jnp.asarray(self._wo), n_steps=k)
         self.k_pages[:, used] = np.asarray(kc_o)[:, :len(used)]
         self.v_pages[:, used] = np.asarray(vc_o)[:, :len(used)]
         toks = np.asarray(toks)

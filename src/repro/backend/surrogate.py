@@ -55,7 +55,7 @@ class PagedSurrogateBackend:
     def __init__(self, *, block_size: int, num_blocks: int,
                  num_swap_blocks: int = 0, copy_streams: int = 0,
                  n_heads: int = 4, n_kv_heads: int = 2, head_dim: int = 16,
-                 vocab: int = 256, seed: int = 0, interpret: bool = True,
+                 vocab: int = 256, seed: int = 0,
                  kv_dtype: str = "float32"):
         if kv_dtype not in ("float32", "int8"):
             raise ValueError(f"kv_dtype must be float32|int8, got {kv_dtype}")
@@ -74,7 +74,6 @@ class PagedSurrogateBackend:
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.vocab = vocab
-        self.interpret = interpret
         self._embed_dim = n_heads * head_dim
         rng = np.random.default_rng(seed)
         scale = 1.0 / np.sqrt(self._embed_dim)

@@ -4,14 +4,14 @@ Every assigned architecture provides a ``ModelConfig`` (exact public
 hyperparameters) plus the shared shape grid (train_4k / prefill_32k /
 decode_32k / long_500k).  ``input_specs`` builds ShapeDtypeStruct stand-ins
 for the dry-run (never allocates device memory).
+
+Importing this package does not import JAX: the serving owner process
+sizes its workers from a config and must stay off the accelerator.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Sequence, Tuple
-
-import jax
-import jax.numpy as jnp
 
 # ---------------------------------------------------------------------------
 # Architecture config
@@ -113,6 +113,7 @@ class ModelConfig:
         return out
 
     def param_dtype(self):
+        import jax.numpy as jnp
         return jnp.dtype(self.dtype)
 
     def scaled(self, **overrides) -> "ModelConfig":
@@ -159,6 +160,8 @@ def cell_applicable(config: ModelConfig, cell: ShapeCell) -> Tuple[bool, str]:
 
 
 def _sd(shape, dtype):
+    import jax
+    import jax.numpy as jnp
     return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
 
 
@@ -175,13 +178,13 @@ def input_specs(config: ModelConfig, cell: ShapeCell) -> dict:
     B, S = cell.global_batch, cell.seq_len
     specs: dict = {}
     if cell.kind == "train":
-        specs["tokens"] = _sd((B, S), jnp.int32)
-        specs["targets"] = _sd((B, S), jnp.int32)
+        specs["tokens"] = _sd((B, S), "int32")
+        specs["targets"] = _sd((B, S), "int32")
     elif cell.kind == "prefill":
-        specs["tokens"] = _sd((B, S), jnp.int32)
+        specs["tokens"] = _sd((B, S), "int32")
     else:  # decode: one new token against a cache of S
-        specs["tokens"] = _sd((B, 1), jnp.int32)
-        specs["cache_len"] = _sd((), jnp.int32)
+        specs["tokens"] = _sd((B, 1), "int32")
+        specs["cache_len"] = _sd((), "int32")
 
     if (config.family == "audio" and config.encdec is not None
             and cell.kind != "decode"):
@@ -195,5 +198,5 @@ def input_specs(config: ModelConfig, cell: ShapeCell) -> dict:
         # embeddings are precomputed and merged upstream (stub), so the
         # backbone consumes token ids + positions.
         pos_len = 1 if cell.kind == "decode" else S
-        specs["mrope_positions"] = _sd((3, B, pos_len), jnp.int32)
+        specs["mrope_positions"] = _sd((3, B, pos_len), "int32")
     return specs

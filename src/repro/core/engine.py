@@ -9,8 +9,11 @@
   results mp.Queue -> client
 
 Everything host-side is real (real processes, real /dev/shm ring, real
-tokenizer CPU burn); the accelerator step is emulated from a DeviceModel
-(sleep with roofline-derived duration) since this container has no TPU.
+tokenizer CPU burn).  The accelerator step is emulated from a DeviceModel
+(sleep with roofline-derived duration) by default; with
+``backend="jax"`` each worker holds one chip of its own and runs the
+paged Pallas decode there.  Only the workers import JAX.  A worker or
+EngineCore that dies fails ``collect`` at once, with its error.
 This is the instrumented system the paper's experiments (Figs 5-13) run on.
 """
 from __future__ import annotations
@@ -22,9 +25,11 @@ import os
 import queue
 import threading
 import time
-from typing import Any, Dict, List, Optional
+import traceback
+from typing import TYPE_CHECKING, Any, Dict, List, Optional
 
 from repro import profiling
+from repro.core import chip
 from repro.core.devmodel import DeviceModel
 from repro.core.shm_broadcast import CompletionBoard, ShmBroadcastQueue
 from repro.profiling import ProfilingConfig
@@ -33,6 +38,9 @@ from repro.serving.scheduler import (BlockTableTracker, Scheduler,
                                      SchedulerConfig, StepPlan)
 from repro.tokenizer.bpe import BPETokenizer, default_tokenizer
 from repro.tokenizer.pool import TokenizerPool
+
+if TYPE_CHECKING:
+    from repro.configs import ModelConfig
 
 _CTX = mp.get_context("fork")
 
@@ -56,6 +64,9 @@ class EngineConfig:
     draft_backend: str = ""                 # "" = default for the target
     # KV pool precision on the decode tier ("float32" | "int8")
     kv_dtype: str = "float32"
+    # widths (heads, kv heads, head dim, vocabulary) of the physical
+    # backends' surrogate model; None keeps its small default widths
+    model: Optional["ModelConfig"] = None
     ring_slots: int = 8
     # 0 = auto-size from the scheduler config: plans carry block tables +
     # input ids, so a slot must hold max_tokens_per_step input ids plus the
@@ -77,6 +88,14 @@ class EngineConfig:
     # default — every process takes the uninstrumented fast path unless
     # this (or REPRO_INJECT/REPRO_TRACE) asks for a profiler
     profiling: ProfilingConfig = ProfilingConfig()
+
+    @property
+    def uses_jax(self) -> bool:
+        """Whether a worker runs a JAX backend and so needs a chip."""
+        leaves = {self.backend, self.draft_backend}
+        if self.backend == "hybrid":
+            leaves |= {self.prefill_backend, self.decode_backend}
+        return "jax" in leaves
 
     def resolved_ring_slot_bytes(self) -> int:
         if self.ring_slot_bytes:
@@ -247,9 +266,26 @@ def _worker(cfg: EngineConfig, idx: int, ring_name: str, board_name: str,
 
     Execution goes through the pluggable backend seam: "emulated" keeps
     the calibrated device-model sleep, "jax" runs the paged pallas decode
-    for real (constructed post-fork, so jax state is never inherited)."""
+    for real on chip ``idx`` (pinned and constructed post-fork, so jax
+    state is never inherited).  An exception is posted to the owner
+    before it ends the process."""
+    try:
+        _worker_loop(cfg, idx, ring_name, board_name, stats_q)
+    except BaseException:
+        stats_q.put({"role": f"worker{idx}",
+                     "error": traceback.format_exc()})
+        raise
+
+
+def _worker_loop(cfg: EngineConfig, idx: int, ring_name: str,
+                 board_name: str, stats_q) -> None:
     from repro.backend import make_backend   # deferred: avoids core<->backend
                                              # import cycle at package load
+    device = None
+    if cfg.uses_jax:
+        chip.pin_chip(idx)
+        chip.enable_compile_cache()
+        device = chip.device_info()
     prof = profiling.activate(cfg.profiling, role=f"worker{idx}")
     ring = ShmBroadcastQueue.attach(ring_name)
     reader = ring.reader(idx)
@@ -260,7 +296,8 @@ def _worker(cfg: EngineConfig, idx: int, ring_name: str, board_name: str,
                            decode_backend=cfg.decode_backend,
                            decode_slowdown=cfg.decode_slowdown,
                            kv_dtype=cfg.kv_dtype,
-                           draft_backend=cfg.draft_backend)
+                           draft_backend=cfg.draft_backend,
+                           model=cfg.model)
     tables = BlockTableTracker()      # delta plans -> full tables
     while True:
         payload, _ = reader.dequeue(timeout=600.0,
@@ -283,6 +320,7 @@ def _worker(cfg: EngineConfig, idx: int, ring_name: str, board_name: str,
         board.mark(idx, plan.step_id)
     stats_q.put({
         "role": f"worker{idx}",
+        "device": device,
         "dequeue_wall": [s.wall_s for s in reader.stats],
         "dequeue_spins": [s.spins for s in reader.stats],
         "trace_events": prof.events if prof is not None else [],
@@ -315,6 +353,7 @@ class ServingSystem:
         self._lock = threading.Lock()
         self._encode_futs: List["cf.Future"] = []
         self._prof = None
+        self.failures: List[str] = []
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -402,28 +441,50 @@ class ServingSystem:
         return self._last_pressure
 
     def collect(self, n: int, timeout: float = 300.0) -> Dict[int, dict]:
+        """Wait for ``n`` result records (finished or timed out) or the
+        timeout.  Raises ``RuntimeError`` as soon as an engine or worker
+        process has died, with the error it posted."""
         deadline = time.monotonic() + timeout
         while len(self.results) < n and time.monotonic() < deadline:
             try:
                 rec = self.out_q.get(timeout=0.2)
                 self.results[rec["req_id"]] = rec
             except queue.Empty:
-                continue
+                dead = self._dead()
+                if dead:
+                    self._drain_stats()
+                    errors = [s["error"] for s in self.stats if "error" in s]
+                    raise RuntimeError(f"{', '.join(dead)} died"
+                                       + "".join("\n" + e for e in errors))
         return self.results
 
-    def shutdown(self, timeout: float = 30.0) -> List[dict]:
-        self.stop_ev.set()
-        deadline = time.monotonic() + timeout
-        for p in self.procs:
-            p.join(max(0.1, deadline - time.monotonic()))
+    def _dead(self) -> List[str]:
+        """Engine and worker processes that have exited abnormally."""
+        return [f"{p.name} (exit code {p.exitcode})" for p in self.procs
+                if p.exitcode not in (None, 0)]
+
+    def _drain_stats(self) -> None:
         while True:
             try:
                 self.stats.append(self.stats_q.get_nowait())
             except queue.Empty:
                 break
+
+    def shutdown(self, timeout: float = 30.0) -> List[dict]:
+        """Stop every process and return their stats.  ``failures`` then
+        names the processes that died on their own; after such a death
+        the rest are not waited for, since the engine would sit out its
+        barrier timeout."""
+        self.stop_ev.set()
+        deadline = time.monotonic() + (0.0 if self._dead() else timeout)
+        for p in self.procs:
+            p.join(max(0.1, deadline - time.monotonic()))
+        self._drain_stats()
+        self.failures = self._dead()
         for p in self.procs:
             if p.is_alive():
                 p.terminate()
+                p.join(5.0)
         if self.pool:
             self.pool.shutdown()
         self.ring.close()

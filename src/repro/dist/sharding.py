@@ -194,14 +194,7 @@ def shard(x, *axes: LogicalAxis):
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map`` (jax>=0.5 top-level vs experimental).
-
-    ``check_vma`` maps onto the older ``check_rep`` flag; both default off
-    because the MoE/embedding bodies do manual psums over "model".
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with ``check_vma`` off by default, because the
+    MoE/embedding bodies do manual psums over "model"."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -1,5 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
+# the dry-run meshes (up to 512 devices) are virtual CPU devices
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 DOC = """Multi-pod dry-run driver (deliverable e).
 

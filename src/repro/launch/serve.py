@@ -6,15 +6,20 @@
 Runs the instrumented control plane (API-server tokenizer pool -> EngineCore
 -> shm broadcast -> workers) on this machine, restricted to ``--cores``
 logical CPUs (the paper's salloc-style budget), and reports TTFT /
-tokenize / dequeue statistics.
+tokenize / dequeue statistics.  With ``--backend jax`` each of the
+``--tp`` workers holds one TPU chip and runs the paged decode at the
+widths of ``--arch``; this process never imports JAX.  The run exits
+non-zero when an engine or worker process dies.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import statistics as st
+import sys
 import time
 
+from repro.configs import ARCHS, get_config
 from repro.core.cpuutil import CpuSampler, cpu_budget
 from repro.core.devmodel import DeviceModel
 from repro.core.engine import EngineConfig, ServingSystem
@@ -44,6 +49,9 @@ def main() -> None:
                          "paged pallas decode, cpu the NumPy decode path "
                          "(keep --kv-capacity small for both), hybrid "
                          "splits prefill/decode across two child backends")
+    ap.add_argument("--arch", default="qwen2-0.5b", choices=sorted(ARCHS),
+                    help="configuration whose heads, kv heads, head dim "
+                         "and vocabulary size the physical backends' model")
     ap.add_argument("--prefill-backend", default="emulated",
                     choices=("emulated", "jax", "cpu"),
                     help="hybrid only: accelerator-tier child executing "
@@ -177,6 +185,10 @@ def main() -> None:
     got = cpu_budget(args.cores)
     physical = {args.backend} | ({args.prefill_backend, args.decode_backend}
                                  if args.backend == "hybrid" else set())
+    model = get_config(args.arch)
+    if physical & {"jax", "cpu"} and not model.n_heads:
+        ap.error(f"--arch {args.arch} has no attention heads to size the "
+                 "physical backends' model from")
     if not args.kv_capacity:
         args.kv_capacity = ((1 << 16) if physical & {"jax", "cpu"}
                             else (1 << 22))
@@ -216,6 +228,7 @@ def main() -> None:
         decode_slowdown=args.decode_slowdown,
         draft_backend=args.draft_backend,
         kv_dtype=args.kv_dtype,
+        model=model,
         ring_slot_bytes=args.ring_slot_bytes,
         yield_every=args.yield_every, async_sched=args.async_sched,
         pressure_every=(4 if args.replicas > 1 else 0),
@@ -227,7 +240,8 @@ def main() -> None:
         backend_desc += (f"[{args.prefill_backend}->prefill, "
                          f"{args.decode_backend}->decode]")
     print(f"[serve] tp={args.tp} cores={got} pool={args.pool_width} "
-          f"backend={backend_desc} async_sched={args.async_sched} "
+          f"backend={backend_desc} arch={args.arch} "
+          f"async_sched={args.async_sched} "
           f"preemption={args.preemption_policy} "
           f"victims={args.victim_selection} "
           f"copy_streams={args.copy_streams} "
@@ -244,18 +258,20 @@ def main() -> None:
 
     sys_ = ServingSystem(cfg).start()
     slo_mix = SLOMix(parse_slo_mix(args.slo_mix)) if args.slo_mix else None
-    with CpuSampler(0.05) as sampler:
-        t0 = time.perf_counter()
-        for i in range(args.requests):
-            target = t0 + i / args.rps
-            now = time.perf_counter()
-            if target > now:
-                time.sleep(target - now)
-            sys_.submit(text, max_new_tokens=args.max_new,
-                        is_victim=(i % 5 == 0),
-                        slo=slo_mix.next() if slo_mix else None)
-        results = sys_.collect(args.requests, timeout=120.0)
-    stats = sys_.shutdown()
+    try:
+        with CpuSampler(0.05) as sampler:
+            t0 = time.perf_counter()
+            for i in range(args.requests):
+                target = t0 + i / args.rps
+                now = time.perf_counter()
+                if target > now:
+                    time.sleep(target - now)
+                sys_.submit(text, max_new_tokens=args.max_new,
+                            is_victim=(i % 5 == 0),
+                            slo=slo_mix.next() if slo_mix else None)
+            results = sys_.collect(args.requests, timeout=120.0)
+    finally:
+        stats = sys_.shutdown()
 
     if args.trace_out:
         pairs = events_from_stats(stats)
@@ -270,15 +286,19 @@ def main() -> None:
     toks = sorted(r["t_tokenize_done"] - r["t_tokenize_start"]
                   for r in finished)
     n_dead = len(results) - len(finished)
+    gen = [r["n_generated"] for r in finished]
     print(f"[serve] completed {len(finished)}/{args.requests}"
-          + (f" (timed out/rejected: {n_dead})" if n_dead else ""))
+          + (f" (timed out/rejected: {n_dead})" if n_dead else "")
+          + (f" generated min={min(gen)} max={max(gen)}" if gen else ""))
     if ttfts:
         print(f"[serve] TTFT p50={st.median(ttfts)*1e3:.1f}ms "
               f"p95={ttfts[int(0.95 * (len(ttfts) - 1))]*1e3:.1f}ms "
               f"max={ttfts[-1]*1e3:.1f}ms")
         print(f"[serve] tokenize p50={st.median(toks)*1e3:.2f}ms")
     for s in stats:
-        if s["role"].startswith("worker"):
+        if s.get("device"):
+            print(f"[serve] {s['role']} device {json.dumps(s['device'])}")
+        if s["role"].startswith("worker") and "dequeue_wall" in s:
             dq = s["dequeue_wall"]
             if dq:
                 print(f"[serve] {s['role']} dequeue p50="
@@ -290,12 +310,19 @@ def main() -> None:
     if eng and eng["sched_cost"]:
         print(f"[serve] sched p50={st.median(eng['sched_cost'])*1e6:.0f}us "
               f"steps={len(eng['sched_cost'])} "
+              f"broadcasts={len(eng['enqueue_wall'])} "
               f"barrier p50={st.median(eng['barrier_wall'])*1e3:.2f}ms")
     if eng and eng.get("payload_bytes"):
         pb = eng["payload_bytes"]
         print(f"[serve] broadcast payload p50={st.median(pb)/1024:.2f}KiB "
               f"max={max(pb)/1024:.2f}KiB total={sum(pb)/1024:.0f}KiB")
     print(f"[serve] cpu saturation(>=95%)={sampler.saturation_seconds():.1f}s")
+    _exit_on_failures(sys_.failures)
+
+
+def _exit_on_failures(failures) -> None:
+    if failures:
+        sys.exit(f"[serve] failed: {', '.join(failures)} died")
 
 
 def _print_slo(snap, tag: str) -> None:
@@ -325,23 +352,25 @@ def _serve_fleet(args, cfg: EngineConfig, base_text: str) -> None:
     fleet = FleetServingFrontend([cfg] * args.replicas,
                                  routing=args.routing).start()
     slo_mix = SLOMix(parse_slo_mix(args.slo_mix)) if args.slo_mix else None
-    with CpuSampler(0.05) as sampler:
-        t0 = time.perf_counter()
-        for i in range(args.requests):
-            target = t0 + i / args.rps
-            now = time.perf_counter()
-            if target > now:
-                time.sleep(target - now)
-            sid = i % max(1, args.sessions)
-            text = (f"session {sid} shared context preamble " * 8
-                    + base_text)
-            fleet.submit(text, max_new_tokens=args.max_new,
-                         is_victim=(i % 5 == 0), session=sid,
-                         slo=slo_mix.next() if slo_mix else None)
-        results = fleet.collect(args.requests, timeout=120.0)
-    pressures = fleet.pressure()
-    router = fleet.router.stats()
-    all_stats = fleet.shutdown()
+    try:
+        with CpuSampler(0.05) as sampler:
+            t0 = time.perf_counter()
+            for i in range(args.requests):
+                target = t0 + i / args.rps
+                now = time.perf_counter()
+                if target > now:
+                    time.sleep(target - now)
+                sid = i % max(1, args.sessions)
+                text = (f"session {sid} shared context preamble " * 8
+                        + base_text)
+                fleet.submit(text, max_new_tokens=args.max_new,
+                             is_victim=(i % 5 == 0), session=sid,
+                             slo=slo_mix.next() if slo_mix else None)
+            results = fleet.collect(args.requests, timeout=120.0)
+        pressures = fleet.pressure()
+        router = fleet.router.stats()
+    finally:
+        all_stats = fleet.shutdown()
 
     if args.trace_out:
         flat = [dict(s, role=f"r{idx}/{s['role']}")
@@ -402,6 +431,7 @@ def _serve_fleet(args, cfg: EngineConfig, base_text: str) -> None:
             print(f"[fleet] replica{idx} sched p50="
                   f"{st.median(eng['sched_cost'])*1e6:.0f}us "
                   f"steps={len(eng['sched_cost'])}")
+    _exit_on_failures([f for s in fleet.systems for f in s.failures])
 
 
 if __name__ == "__main__":

@@ -22,9 +22,10 @@ Decode reads a [B, S, KVs, Dh] cache sharded on the *sequence* dim when kv
 heads don't divide tp (flash-decoding: XLA's partial-softmax reductions
 turn into small cross-shard collectives) or on kv heads when they do.
 
-On TPU the inner block computation is replaced by the Pallas flash kernel
-(`repro.kernels.flash_attention`); this module is the jnp path that the
-dry-run lowers (see DESIGN.md §6).
+The model stack runs no Pallas kernel today, on any platform: this jnp
+path is what both the chip and the dry-run execute.  The Pallas kernels
+in `repro.kernels` are tested against their references but not called
+from here.
 """
 from __future__ import annotations
 

@@ -68,8 +68,7 @@ def test_emulated_jax_conformance():
         EmulatedBackend(DeviceModel(t_fixed=1e-5, t_prefill_tok=1e-8,
                                     t_decode_seq=1e-6)))
     jx_order, jx_counts, jx_tokens = _drive(
-        JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128,
-                   interpret=True))
+        JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128))
     assert em_order == jx_order
     assert em_counts == jx_counts
     # the jax backend actually samples (not the emulated placeholder 0)
@@ -78,9 +77,9 @@ def test_emulated_jax_conformance():
 
 def test_jax_backend_is_deterministic():
     _, _, a = _drive(JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS,
-                                vocab=128, interpret=True))
+                                vocab=128))
     _, _, b = _drive(JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS,
-                                vocab=128, interpret=True))
+                                vocab=128))
     assert a == b
 
 
@@ -128,7 +127,7 @@ def test_paged_kernel_matches_reference():
         used += n_pages
         sl[b] = n_tok
     out = paged_decode_attention(q, kp, vp, jnp.asarray(bt),
-                                 jnp.asarray(sl), interpret=True)
+                                 jnp.asarray(sl))
     ref = paged_decode_attention_reference(q, kp, vp, jnp.asarray(bt),
                                            jnp.asarray(sl))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -143,7 +142,7 @@ def test_jax_swap_round_trip_restores_identical_contents():
     from repro.serving.scheduler import StepPlan
 
     be = JaxBackend(block_size=8, num_blocks=16, num_swap_blocks=8,
-                    vocab=64, interpret=True)
+                    vocab=64)
     toks = [3 + (i % 60) for i in range(16)]          # two full blocks
     be.execute(StepPlan(1, [(1, 0, 16)], [], [],
                         block_tables={1: [3, 7]}, new_tokens={1: toks}))
@@ -177,7 +176,7 @@ def test_swap_policy_conformance_with_jax_backend():
             swap_capacity_tokens=32 * BLOCK)
         backend = JaxBackend(block_size=BLOCK, num_blocks=cfg.num_kv_blocks,
                              num_swap_blocks=cfg.num_swap_blocks,
-                             vocab=128, interpret=True)
+                             vocab=128)
         sched = Scheduler(cfg)
         reqs = []
         for i, (n, m) in enumerate([(40, 8), (37, 8)]):
@@ -209,8 +208,7 @@ def test_jax_backend_shares_prefix_pages():
     the first's cached pages, and the jax backend decodes it correctly
     against KV it never wrote itself."""
     sched = Scheduler(SCHED_CFG)
-    backend = JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128,
-                         interpret=True)
+    backend = JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128)
 
     def run_one(stream_tokens, max_new=3):
         r = Request(text="", max_new_tokens=max_new)

@@ -45,7 +45,7 @@ PRESSURE_CFG = SchedulerConfig(
 
 def make(name: str, cfg: SchedulerConfig):
     kw = dict(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
-              num_swap_blocks=cfg.num_swap_blocks, vocab=128, interpret=True)
+              num_swap_blocks=cfg.num_swap_blocks, vocab=128)
     if name == "emulated":
         return EmulatedBackend(DeviceModel(t_fixed=1e-5, t_prefill_tok=1e-8,
                                            t_decode_seq=1e-6))

@@ -1,8 +1,13 @@
 """Control-plane tests: shm ring, completion board, end-to-end engine."""
 from __future__ import annotations
 
+import json
 import multiprocessing as mp
+import os
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import pytest
 
@@ -230,3 +235,75 @@ def test_engine_end_to_end(async_sched):
     assert "engine" in roles and "worker0" in roles and "worker1" in roles
     eng = next(s for s in stats if s["role"] == "engine")
     assert eng["sched_cost"], "scheduler cost must be measured"
+
+
+# Runs in a fresh interpreter: a worker forked from a process in which JAX
+# has already started (as it has in a test process) can hang in JAX.
+_JAX_ENGINE = """
+import json
+import sys
+from repro.configs import get_config
+from repro.core.engine import EngineConfig, ServingSystem
+from repro.serving.scheduler import SchedulerConfig
+
+cfg = EngineConfig(
+    tp_degree=1, pool_width=1, backend="jax", yield_every=64,
+    model=get_config("qwen2-0.5b").scaled(d_model=64, n_heads=4,
+                                          n_kv_heads=2, vocab_size=256),
+    scheduler=SchedulerConfig(kv_capacity_tokens=512, block_size=8))
+sys_ = ServingSystem(cfg).start()
+try:
+    for i in range(3):
+        sys_.submit("the quick brown fox " * (2 + i), max_new_tokens=3)
+    results = sys_.collect(3, timeout=120.0)
+finally:
+    stats = sys_.shutdown()
+print(json.dumps({"results": list(results.values()),
+                  "devices": {s["role"]: s.get("device") for s in stats},
+                  "failures": sys_.failures,
+                  "owner_imported_jax": "jax" in sys.modules}))
+"""
+
+
+def test_engine_jax_backend_end_to_end():
+    """The live engine with ``backend="jax"`` at small explicit widths: the
+    worker picks the kernel's interpreter from the platform, every request
+    completes, the worker reports the device it ran on, and the owner
+    process never imports JAX."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    proc = subprocess.run([sys.executable, "-c", _JAX_ENGINE], env=env,
+                          capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["failures"] == []
+    assert len(out["results"]) == 3
+    for rec in out["results"]:
+        assert not rec["timed_out"] and rec["n_generated"] == 3
+    dev = out["devices"]["worker0"]
+    assert dev["platform"] == "cpu" and dev["device_kind"] == "cpu"
+    assert isinstance(dev["id"], int)
+    assert out["devices"]["engine"] is None
+    assert out["owner_imported_jax"] is False
+
+
+def test_worker_failure_fails_serving_promptly():
+    """A worker that raises while building its backend fails ``collect``
+    within seconds with its error, not after the 120 s barrier timeout,
+    and shutdown does not wait out the stuck engine."""
+    cfg = EngineConfig(tp_degree=2, pool_width=1, backend="cpu",
+                       kv_dtype="bfloat8", yield_every=64,
+                       scheduler=SchedulerConfig(kv_capacity_tokens=512,
+                                                 block_size=8))
+    sys_ = ServingSystem(cfg).start()
+    try:
+        sys_.submit("the quick brown fox", max_new_tokens=2)
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError, match="kv_dtype must be"):
+            sys_.collect(1, timeout=60.0)
+        assert time.monotonic() - t0 < 20.0
+    finally:
+        t0 = time.monotonic()
+        sys_.shutdown()
+        assert time.monotonic() - t0 < 15.0
+    assert any(f.startswith("worker-") for f in sys_.failures)

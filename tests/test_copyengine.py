@@ -52,7 +52,7 @@ def pressure_cfg(copy_streams: int, **kw) -> SchedulerConfig:
 def make_physical(name: str, cfg: SchedulerConfig):
     kw = dict(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
               num_swap_blocks=cfg.num_swap_blocks,
-              copy_streams=cfg.copy_streams, vocab=128, interpret=True)
+              copy_streams=cfg.copy_streams, vocab=128)
     if name == "jax":
         return JaxBackend(**kw)
     if name == "cpu":

@@ -41,8 +41,7 @@ def _cfg(k: int = 1, *, blocks: int = 64, **kw) -> SchedulerConfig:
 
 def _backend(name: str, cfg: SchedulerConfig):
     kw = dict(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
-              num_swap_blocks=max(cfg.num_swap_blocks, 1), vocab=128,
-              interpret=True)
+              num_swap_blocks=max(cfg.num_swap_blocks, 1), vocab=128)
     if name == "emulated":
         return EmulatedBackend(DeviceModel(t_fixed=1e-5, t_prefill_tok=1e-8,
                                            t_decode_seq=1e-6))

@@ -68,7 +68,7 @@ def make(name: str, cfg: SchedulerConfig):
     from repro.backend.jax_backend import JaxBackend
     kw = dict(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
               num_swap_blocks=cfg.num_swap_blocks,
-              copy_streams=cfg.copy_streams, vocab=128, interpret=True)
+              copy_streams=cfg.copy_streams, vocab=128)
     if name == "emulated":
         return EmulatedBackend(DeviceModel(t_fixed=1e-5, t_prefill_tok=1e-8,
                                            t_decode_seq=1e-6,

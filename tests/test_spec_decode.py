@@ -39,7 +39,7 @@ def _cfg(spec_k: int = 0, *, blocks: int = 64, **kw) -> SchedulerConfig:
 def _kw(cfg: SchedulerConfig, **extra) -> dict:
     return dict(block_size=cfg.block_size, num_blocks=cfg.num_kv_blocks,
                 num_swap_blocks=max(cfg.num_swap_blocks, 1), vocab=128,
-                interpret=True, copy_streams=cfg.copy_streams, **extra)
+                copy_streams=cfg.copy_streams, **extra)
 
 
 def _target(name: str, cfg: SchedulerConfig, kv_dtype: str = "float32"):
@@ -382,8 +382,7 @@ def test_paged_kernel_hbm_path_matches_reference(int8):
         paged_decode_attention_reference,
     )
     args, kw = _paged_case(np.random.default_rng(7), int8=int8)
-    out = paged_decode_attention(*args, **kw, vmem_budget_bytes=1024,
-                                 interpret=True)
+    out = paged_decode_attention(*args, **kw, vmem_budget_bytes=1024)
     ref = paged_decode_attention_reference(*args, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -393,10 +392,8 @@ def test_paged_kernel_hbm_agrees_with_vmem_path():
     """Same inputs through both residency paths: identical numerics."""
     from repro.kernels.paged_decode_attention import paged_decode_attention
     args, kw = _paged_case(np.random.default_rng(9), int8=True)
-    hbm = paged_decode_attention(*args, **kw, pool_in_vmem=False,
-                                 interpret=True)
-    vmem = paged_decode_attention(*args, **kw, pool_in_vmem=True,
-                                  interpret=True)
+    hbm = paged_decode_attention(*args, **kw, pool_in_vmem=False)
+    vmem = paged_decode_attention(*args, **kw, pool_in_vmem=True)
     np.testing.assert_allclose(np.asarray(hbm), np.asarray(vmem),
                                atol=1e-6, rtol=1e-6)
 
@@ -412,8 +409,7 @@ def test_paged_kernel_int8_drift_vs_fp32_bounded():
     fp_args, _ = _paged_case(rng, int8=False)
     q_args, q_kw = _paged_case(np.random.default_rng(7), int8=True)
     want = paged_decode_attention_reference(*fp_args)
-    got = paged_decode_attention(*q_args, **q_kw, vmem_budget_bytes=1024,
-                                 interpret=True)
+    got = paged_decode_attention(*q_args, **q_kw, vmem_budget_bytes=1024)
     rows = np.asarray(fp_args[4]) > 0        # seq_len 0 rows are inert
     drift = np.abs(np.asarray(got) - np.asarray(want))[rows].max()
     assert drift < 0.05, drift
