@@ -16,6 +16,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
+from repro import profiling
 from repro.backend.base import StepResult
 from repro.core.devmodel import DeviceModel
 from repro.serving.scheduler import StepPlan
@@ -35,7 +36,11 @@ class EmulatedBackend:
                 ) -> StepResult:
         t = self.step_cost(plan)
         if self.sleep:
-            time.sleep(t)
+            # the sleep is this backend's device: the cover set of
+            # profiling.critical_path_summary
+            with profiling.span("device_wait", step=plan.step_id,
+                                phase=plan.phase):
+                time.sleep(t)
         # placeholder sampling: token 0 for every scheduled request (the
         # emulated device computes nothing — counts/order still exercise
         # the full control plane)

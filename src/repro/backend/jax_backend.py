@@ -94,40 +94,46 @@ class JaxBackend(PagedSurrogateBackend):
         are padded to power-of-2 buckets so the jitted pallas call
         compiles once per bucket, not once per batch composition."""
         rows = q.shape[0]
-        used = np.unique(tables[tables >= 0])
-        remap = np.full(self.num_blocks, -1, np.int32)
-        remap[used] = np.arange(len(used), dtype=np.int32)
-        compact = np.where(tables >= 0,
-                           remap[np.clip(tables, 0, None)], -1)
-        rows_p = _pow2_at_least(rows, 2)
-        nb_p = _pow2_at_least(max(tables.shape[1], 1), 2)
-        pool_p = _pow2_at_least(max(len(used), 1), 2)
-        quant = self.kv_dtype == "int8"
-        qp = np.zeros((rows_p, self.n_heads, self.head_dim), np.float32)
-        qp[:rows] = q
-        bt = np.full((rows_p, nb_p), -1, np.int32)
-        bt[:rows, :tables.shape[1]] = compact
-        sl = np.zeros((rows_p,), np.int32)
-        sl[:rows] = seq_lens
-        kc = np.zeros((self.n_kv_heads, pool_p, self.block_size,
-                       self.head_dim),
-                      np.int8 if quant else np.float32)
-        vc = np.zeros_like(kc)
-        kc[:, :len(used)] = self.k_pages[:, used]
-        vc[:, :len(used)] = self.v_pages[:, used]
-        scales = {}
-        if quant:
-            # ship int8 codes + per-page scales; the kernel dequantizes
+        with self._span("page_gather"):
+            used = np.unique(tables[tables >= 0])
+            remap = np.full(self.num_blocks, -1, np.int32)
+            remap[used] = np.arange(len(used), dtype=np.int32)
+            compact = np.where(tables >= 0,
+                               remap[np.clip(tables, 0, None)], -1)
+            rows_p = _pow2_at_least(rows, 2)
+            nb_p = _pow2_at_least(max(tables.shape[1], 1), 2)
+            pool_p = _pow2_at_least(max(len(used), 1), 2)
+            quant = self.kv_dtype == "int8"
+            qp = np.zeros((rows_p, self.n_heads, self.head_dim), np.float32)
+            qp[:rows] = q
+            bt = np.full((rows_p, nb_p), -1, np.int32)
+            bt[:rows, :tables.shape[1]] = compact
+            sl = np.zeros((rows_p,), np.int32)
+            sl[:rows] = seq_lens
+            kc = np.zeros((self.n_kv_heads, pool_p, self.block_size,
+                           self.head_dim),
+                          np.int8 if quant else np.float32)
+            vc = np.zeros_like(kc)
+            kc[:, :len(used)] = self.k_pages[:, used]
+            vc[:, :len(used)] = self.v_pages[:, used]
+            # int8: ship codes + per-page scales; the kernel dequantizes
             # on load, so HBM->VMEM traffic is the halved-byte pool
-            ks = np.zeros((self.n_kv_heads, pool_p), np.float32)
-            vs = np.zeros_like(ks)
-            ks[:, :len(used)] = self.k_scales[:, used]
-            vs[:, :len(used)] = self.v_scales[:, used]
-            scales = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
-        logits = attend_logits(
-            jnp.asarray(qp), jnp.asarray(kc), jnp.asarray(vc),
-            jnp.asarray(bt), jnp.asarray(sl), jnp.asarray(self._wo), **scales)
-        return np.asarray(logits)[:rows]
+            host_scales = {}
+            if quant:
+                ks = np.zeros((self.n_kv_heads, pool_p), np.float32)
+                vs = np.zeros_like(ks)
+                ks[:, :len(used)] = self.k_scales[:, used]
+                vs[:, :len(used)] = self.v_scales[:, used]
+                host_scales = dict(k_scales=ks, v_scales=vs)
+        host = (qp, kc, vc, bt, sl, self._wo)
+        with self._span("upload") as sp:
+            scales = {k: jnp.asarray(a) for k, a in host_scales.items()}
+            args = [jnp.asarray(a) for a in host]
+            if sp is not None:
+                sp.n = sum(a.nbytes for a in (*host, *host_scales.values()))
+        with self._span("device_wait"):
+            logits = np.asarray(attend_logits(*args, **scales))
+        return logits[:rows]
 
     # -- fused multi-step decode (docs/multi_step.md) -------------------
 

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import profiling
 from repro.backend.base import PinnedLRU, StepResult
 from repro.core.copyengine import DeferredCopies
 from repro.serving.scheduler import StepPlan
@@ -122,6 +123,18 @@ class PagedSurrogateBackend:
         # req_id -> tokens in cache (see base.PinnedLRU for the aging story)
         self._seq_lens = PinnedLRU(pinned=self._swap_pinned)
         self._last_wall = 0.0
+        self._plan: Optional[StepPlan] = None    # the step in execute
+
+    def _span(self, site: str):
+        """A profiler span at ``site`` tagged with the step being
+        executed; the shared no-op when this process does not trace."""
+        prof = profiling.active()
+        if prof is None:
+            return profiling.NO_SPAN
+        plan = self._plan
+        if plan is None:
+            return prof.span(site)
+        return prof.span(site, step=plan.step_id, phase=plan.phase)
 
     # -- projections ---------------------------------------------------------
 
@@ -257,6 +270,7 @@ class PagedSurrogateBackend:
                 block_tables: Optional[Dict[int, List[int]]] = None
                 ) -> StepResult:
         t0 = time.perf_counter()
+        self._plan = plan
         tables = block_tables if block_tables is not None \
             else plan.block_tables
         for rid in plan.preempted:
@@ -305,14 +319,15 @@ class PagedSurrogateBackend:
         if plan.num_steps > 1:
             return self._execute_multi(plan, tables, t0)
 
-        rows = self._prefill_rows(plan, tables)
-        for rid in plan.decode:
-            table = tables.get(rid, [])
-            tok = int(plan.new_tokens.get(rid, [0])[0])
-            pos = self._seq_lens.get(rid, 0)
-            self._write(table, pos, np.asarray([tok], np.int64))
-            self._track(rid, pos + 1)
-            rows.append((rid, tok, pos + 1, table))
+        with self._span("kv_write"):
+            rows = self._prefill_rows(plan, tables)
+            for rid in plan.decode:
+                table = tables.get(rid, [])
+                tok = int(plan.new_tokens.get(rid, [0])[0])
+                pos = self._seq_lens.get(rid, 0)
+                self._write(table, pos, np.asarray([tok], np.int64))
+                self._track(rid, pos + 1)
+                rows.append((rid, tok, pos + 1, table))
 
         tokens = self._sample_rows(rows)
         self._last_wall = time.perf_counter() - t0
@@ -351,8 +366,9 @@ class PagedSurrogateBackend:
                 bt[i, :len(table)] = table
                 sl[i] = seq_len
             logits = self._attend(q, bt, sl)
-            for i, (rid, _, _, _) in enumerate(rows):
-                tokens[rid] = int(np.argmax(logits[i]))
+            with self._span("sample"):
+                for i, (rid, _, _, _) in enumerate(rows):
+                    tokens[rid] = int(np.argmax(logits[i]))
         return tokens
 
     # -- multi-step macro-plans (docs/multi_step.md) --------------------

@@ -143,6 +143,7 @@ def _engine_core(cfg: EngineConfig, in_q, out_q, stats_q, ring_name: str,
             "t_arrival": req.t_arrival,
             "t_tokenize_start": req.t_tokenize_start,
             "t_tokenize_done": req.t_tokenize_done,
+            "t_first_scheduled": req.t_first_scheduled,
             "t_first_token": req.t_first_token,
             "t_done": req.t_done,
             "n_prompt": req.n_prompt,
@@ -249,7 +250,6 @@ def _engine_core(cfg: EngineConfig, in_q, out_q, stats_q, ring_name: str,
     stats_q.put({
         "role": "engine",
         "enqueue_wall": [s.wall_s for s in writer.stats],
-        "enqueue_spins": [s.spins for s in writer.stats],
         "sched_cost": sched_costs,
         "barrier_wall": barrier_waits,
         "payload_bytes": payload_sizes,
@@ -287,6 +287,11 @@ def _worker_loop(cfg: EngineConfig, idx: int, ring_name: str,
         chip.enable_compile_cache()
         device = chip.device_info()
     prof = profiling.activate(cfg.profiling, role=f"worker{idx}")
+    if prof is not None and prof.trace and cfg.uses_jax:
+        # the worker's spans also land in a device profiler trace, on
+        # its clock (a no-op while no profiler session runs)
+        from jax.profiler import TraceAnnotation
+        prof.annotate = TraceAnnotation
     ring = ShmBroadcastQueue.attach(ring_name)
     reader = ring.reader(idx)
     board = CompletionBoard.attach(board_name, cfg.tp_degree)
@@ -313,16 +318,15 @@ def _worker_loop(cfg: EngineConfig, idx: int, ring_name: str,
             # exposed time by prefill/decode/swap/dispatch (docs/profiling.md)
             with prof.span("dispatch", step=plan.step_id, phase=plan.phase):
                 tables.expand(plan)
-            # trace-only span ("device" is not an injection site): the
-            # cover set critical_path_summary subtracts from exposed time
-            with prof.span("device", step=plan.step_id, phase=plan.phase):
+            # trace-only span ("execute" is not an injection site): the
+            # worker's step, parent of the backend's phase spans
+            with prof.span("execute", step=plan.step_id, phase=plan.phase):
                 backend.execute(plan)
         board.mark(idx, plan.step_id)
     stats_q.put({
         "role": f"worker{idx}",
         "device": device,
         "dequeue_wall": [s.wall_s for s in reader.stats],
-        "dequeue_spins": [s.spins for s in reader.stats],
         "trace_events": prof.events if prof is not None else [],
     })
     ring.close()

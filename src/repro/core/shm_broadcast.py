@@ -55,7 +55,6 @@ _WORD = 8
 @dataclasses.dataclass
 class OpStats:
     wall_s: float
-    spins: int
     payload: int
 
 
@@ -182,7 +181,7 @@ class CompletionBoard:
             if time.perf_counter() - t0 > timeout:
                 raise TimeoutError(f"barrier stalled at step {step}: "
                                    f"{[self._words[i] for i in range(self._n)]}")
-        return OpStats(time.perf_counter() - t0, spins, 0)
+        return OpStats(time.perf_counter() - t0, 0)
 
     def close(self) -> None:
         self._words.release()
@@ -234,7 +233,7 @@ class Writer(_Endpoint):
         self.q._shm.buf[lo:lo + len(payload)] = payload
         w[lay.slot_word(slot, 1)] = len(payload)
         w[lay.slot_word(slot, 0)] = seq           # publish (release)
-        st = OpStats(time.perf_counter() - t0, spins, len(payload))
+        st = OpStats(time.perf_counter() - t0, len(payload))
         self.stats.append(st)
         return st
 
@@ -264,6 +263,6 @@ class Reader(_Endpoint):
         lo, _ = lay.payload_slice(slot)
         payload = bytes(self.q._shm.buf[lo:lo + n])
         w[lay.slot_word(slot, 2 + self.idx)] = self.seq   # ack
-        st = OpStats(time.perf_counter() - t0, spins, n)
+        st = OpStats(time.perf_counter() - t0, n)
         self.stats.append(st)
         return payload, st

@@ -289,6 +289,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         out_shape=jax.ShapeDtypeStruct((B * KV, r, D), q.dtype),
         compiler_params=params,
         interpret=interpret,
+        name="paged_decode_attention",
     )(*prefetch, qg, kp, vp)
     return out.reshape(B, H, D)
 

@@ -42,10 +42,18 @@ Two cooperating halves:
 
 Activation is process-local and explicit: ``activate(cfg, role=...)``
 installs the module-level ``_ACTIVE`` profiler (engine and worker
-processes call it post-fork from ``EngineConfig.profiling``); every
-instrumented call site does ``profiling.active()`` and takes a branch-
-free fast path when it is None — an uninstrumented run executes the
-exact same statements it did before this module existed.
+processes call it post-fork from ``EngineConfig.profiling``); an
+instrumented call site either does ``profiling.active()`` and takes a
+branch-free fast path when it is None — an uninstrumented run executes
+the exact same statements it did before this module existed — or
+enters ``span(...)``, which is then the shared no-op ``NO_SPAN``.
+
+Inside a worker's step (``execute``) the paged backends trace their
+phases: ``kv_write``, ``page_gather``, ``upload`` (its ``n``: bytes
+handed to the device), ``device_wait`` and ``sample``, each with the
+plan's step id.  A JAX worker that traces gives its profiler the
+``annotate`` hook (``jax.profiler.TraceAnnotation``), so the same spans
+land on the host plane of a device profiler trace, on its clock.
 """
 from __future__ import annotations
 
@@ -140,14 +148,18 @@ class SpanEvent:
     req: Optional[int] = None
     instant: bool = False
     phase: Optional[str] = None
+    n: Optional[int] = None     # a count the span carries (upload: bytes)
 
 
 class _Span:
     """Context manager recording one span and applying the site's
     injected delay INSIDE it — the module under measurement really gets
-    slower, and the trace shows the bump where it was charged."""
+    slower, and the trace shows the bump where it was charged.  The body
+    may set ``n`` (``with prof.span(...) as sp: sp.n = ...``).  When the
+    profiler has an ``annotate`` hook, the span also opens an annotation
+    of its name (with ``step=``) around itself."""
 
-    __slots__ = ("prof", "site", "step", "req", "phase", "t0")
+    __slots__ = ("prof", "site", "step", "req", "phase", "t0", "n", "ann")
 
     def __init__(self, prof: "Profiler", site: str,
                  step: Optional[int], req: Optional[int],
@@ -157,8 +169,15 @@ class _Span:
         self.step = step
         self.req = req
         self.phase = phase
+        self.n = None
+        self.ann = None
 
     def __enter__(self) -> "_Span":
+        hook = self.prof.annotate
+        if hook is not None:
+            self.ann = (hook(self.site) if self.step is None
+                        else hook(self.site, step=self.step))
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -171,7 +190,25 @@ class _Span:
         if prof.trace:
             prof.events.append(SpanEvent(
                 self.site, self.t0, time.perf_counter() - self.t0,
-                self.step, self.req, phase=self.phase))
+                self.step, self.req, phase=self.phase, n=self.n))
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+
+
+class _NoSpan:
+    """The span ``span`` gives when no profiler is active: one shared,
+    stateless object that enters to None."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+NO_SPAN = _NoSpan()
 
 
 class Profiler:
@@ -190,6 +227,9 @@ class Profiler:
         self.delays = parse_inject(cfg.inject)
         self.trace = cfg.trace and not virtual
         self.events: List[SpanEvent] = []
+        # e.g. jax.profiler.TraceAnnotation: every span then also lands
+        # in the device profiler's trace, on its clock (the JAX worker)
+        self.annotate = None
         self.pending = 0.0            # virtual mode: undrained seconds
         # lifetime injected seconds (both modes): the denominator of the
         # amplification slope — makespan seconds lost per second injected
@@ -278,6 +318,16 @@ def install(prof: Optional[Profiler]) -> Optional[Profiler]:
     return prev
 
 
+def span(site: str, *, step: Optional[int] = None,
+         req: Optional[int] = None, phase: Optional[str] = None):
+    """The active profiler's span at ``site``, or the shared ``NO_SPAN``
+    when nothing is active (which enters to None and records nothing)."""
+    p = _ACTIVE
+    if p is None:
+        return NO_SPAN
+    return p.span(site, step=step, req=req, phase=phase)
+
+
 def hit(site: str, *, step: Optional[int] = None,
         req: Optional[int] = None, n: int = 1) -> None:
     """Module-level point charge — the one-liner shared call sites use
@@ -323,6 +373,8 @@ def export_chrome_trace(pairs: List[Tuple[str, SpanEvent]],
             args["req"] = ev.req
         if ev.phase is not None:
             args["phase"] = ev.phase
+        if ev.n is not None:
+            args["n"] = ev.n
         rec = {"name": ev.site, "cat": "control-plane",
                "pid": 0, "tid": tid[role],
                "ts": (ev.t0 - t_base) * 1e6, "args": args}
@@ -365,17 +417,20 @@ def _overlap(a0: float, a1: float,
 
 
 def critical_path_summary(pairs: List[Tuple[str, SpanEvent]],
-                          device_site: str = "device") -> Dict[str, dict]:
+                          device_site: str = "device_wait"
+                          ) -> Dict[str, dict]:
     """Per-site totals + the share NOT hidden behind device execution.
 
-    ``device`` spans (the workers' ``backend.execute`` windows) are the
-    cover set: control-plane time that overlaps a device span ran while
-    the accelerators were busy anyway; the *exposed* remainder is time
-    the devices plausibly waited on — the trace-side estimate the
+    ``device_wait`` spans (inside each worker's ``execute``: the host
+    blocked on the device, or the emulated backend's sleep) are the
+    cover set: control-plane time that overlaps one ran while the
+    accelerators were busy anyway; the *exposed* remainder is time the
+    devices plausibly waited on — the trace-side estimate the
     injection sweep's sensitivity slope confirms or refutes per site
     ("time spent ≠ time that matters" runs both ways: exposed-but-
     insensitive spans are slack, hidden-but-sensitive ones are the
-    pipeline's hidden serialization)."""
+    pipeline's hidden serialization).  A worker's ``execute`` row is
+    its whole step; the phase spans inside it are rows of their own."""
     device = _merge_intervals([(ev.t0, ev.t0 + ev.dur)
                                for _, ev in pairs
                                if ev.site == device_site and not ev.instant])
@@ -397,7 +452,7 @@ def critical_path_summary(pairs: List[Tuple[str, SpanEvent]],
 
 
 def phase_summary(pairs: List[Tuple[str, SpanEvent]],
-                  device_site: str = "device") -> Dict[str, dict]:
+                  device_site: str = "device_wait") -> Dict[str, dict]:
     """Flamegraph-style rollup of exposed control-plane time by STEP
     PHASE (``StepPlan.phase``: prefill / decode / mixed / swap /
     dispatch), with a per-site breakdown inside each phase.

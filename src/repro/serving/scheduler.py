@@ -47,6 +47,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import json
+import time
 from typing import Dict, List, Optional, Tuple
 
 from repro.serving.blocks import BlockManager, HostSwapSpace, chain_key
@@ -512,6 +513,9 @@ class PressureStats:
 class Scheduler:
     def __init__(self, cfg: SchedulerConfig = SchedulerConfig()):
         self.cfg = cfg
+        # stamps ``Request.t_first_scheduled``; the DES swaps in its
+        # virtual clock
+        self.clock = time.perf_counter
         self.waiting: List[Request] = []
         self.running: List[Request] = []
         self.swapped: List[Request] = []   # swapped out, FIFO re-admission
@@ -1301,6 +1305,8 @@ class Scheduler:
             self.waiting.pop(wi)
             self.running.append(req)
             req.state = RequestState.PREFILLING
+            if not req.t_first_scheduled:
+                req.t_first_scheduled = self.clock()
             if n > 0:
                 plan.prefill.append((req.req_id, req.prefilled, n))
                 req.prefilled += n

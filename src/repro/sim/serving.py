@@ -146,6 +146,7 @@ class ServingModel:
         self.p = params
         self.sim = Sim(params.n_cores, quantum=params.quantum)
         self.sched = Scheduler(params.scheduler)
+        self.sched.clock = lambda: self.sim.now
         # virtual-time device: the backend's cost model, never its sleep
         if params.decode_device is not None:
             from repro.backend.hybrid import HybridBackend

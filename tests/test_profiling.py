@@ -49,6 +49,10 @@ from repro.sim.serving import (ServingModel, llama8b_tp4_params,
                                with_async_copies, with_multi_step)
 
 BLOCK, NBLOCKS, NSWAP = 8, 64, 32
+# trace-only span sites (no injection): the engine's barrier, the
+# worker's step and the backends' phases inside it
+TRACE_ONLY = ("barrier", "execute", "kv_write", "page_gather", "upload",
+              "device_wait", "sample")
 
 # ~1.5 requests resident under swap: preempt/swap/restore churn
 # (mirrors the pressure configs of the conformance + copy-engine suites)
@@ -282,7 +286,7 @@ def test_trace_wellformed_under_churn(prompt_lens, timeout, abort_every):
         done = ev.t0 + ev.dur
         if ev.req is not None:
             assert ev.req in admitted, ev
-        assert ev.site in SITES or ev.site in ("device", "barrier")
+        assert ev.site in SITES or ev.site in TRACE_ONLY
     # spans balanced: one scheduler span per schedule() call, no more
     n_sched_spans = sum(1 for ev in events
                         if ev.site == "scheduler" and not ev.instant)
@@ -303,7 +307,7 @@ def test_chrome_trace_export_roundtrip(raw, n_roles):
     pairs = []
     for i, v in enumerate(raw):
         role = f"role{v % n_roles}"
-        site = SITES[v % len(SITES)] if v % 3 else "device"
+        site = SITES[v % len(SITES)] if v % 3 else "device_wait"
         pairs.append((role, SpanEvent(site, t0=100.0 + (v % 97) * 1e-4,
                                       dur=(v % 13) * 1e-5,
                                       step=v % 7 or None,
@@ -325,10 +329,10 @@ def test_chrome_trace_export_roundtrip(raw, n_roles):
             assert e["dur"] >= 0.0
         else:
             assert e["s"] == "t"
-    # summary invariants: device is the cover set, never a row; exposed
-    # time is bounded by total time per site
+    # summary invariants: device_wait is the cover set, never a row;
+    # exposed time is bounded by total time per site
     summary = critical_path_summary(pairs)
-    assert "device" not in summary
+    assert "device_wait" not in summary
     for site, s in summary.items():
         assert 0.0 <= s["exposed_s"] <= s["total_s"] + 1e-12, site
         assert s["count"] >= 1
@@ -339,7 +343,8 @@ def test_critical_path_summary_overlap_math():
     """Hand-built timeline: a span fully covered by device time exposes
     nothing, a half-covered one exposes exactly the uncovered half."""
     pairs = events_from_stats([
-        {"role": "w0", "trace_events": [SpanEvent("device", 0.0, 10.0)]},
+        {"role": "w0", "trace_events": [SpanEvent("device_wait", 0.0,
+                                                   10.0)]},
         {"role": "eng", "trace_events": [
             SpanEvent("scheduler", 2.0, 4.0),          # inside device
             SpanEvent("shm_encode", 8.0, 4.0),         # half exposed

@@ -395,7 +395,7 @@ def test_step_plan_phase():
 def test_phase_summary_joins_engine_spans_by_step():
     from repro.profiling import SpanEvent, format_phase_summary, phase_summary
     pairs = [
-        ("worker0", SpanEvent("device", t0=0.0, dur=1.0, step=1)),
+        ("worker0", SpanEvent("device_wait", t0=0.0, dur=1.0, step=1)),
         # worker span carries the phase it observed for step 1 ...
         ("worker0", SpanEvent("dispatch", t0=0.0, dur=0.5, step=1,
                               phase="prefill")),
