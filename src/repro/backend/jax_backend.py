@@ -18,7 +18,9 @@ greedy argmax, deterministic given the seed, so the conformance contract
 
 The two jitted device programs, ``attend_logits`` and ``decode_scan``,
 are module functions so they can be compiled on their own; jit keys their
-executables by the power-of-2 bucketed shapes the backend pads to.
+executables by the power-of-2 bucketed shapes the backend pads to.  Both
+read the surrogate's weights from one device copy each, made on first use
+and kept; a step uploads only its queries, pages, tables and lengths.
 """
 from __future__ import annotations
 
@@ -84,6 +86,29 @@ def decode_scan(kc, vc, bt, sl0, tok0, bud, eos_v, embed, wq, wk, wv, wo, *,
 
 class JaxBackend(PagedSurrogateBackend):
 
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self._on_device: Dict[str, jax.Array] = {}
+
+    def _resident(self, *names: str):
+        """Device copies of the surrogate weights ``names`` (attribute
+        names), and the bytes this call handed to the device.  Weights are
+        final after ``__init__``, so each is copied once, by the first
+        device program that needs it, and kept for the backend's life.
+        ``jnp.asarray`` leaves the copy uncommitted on the default device,
+        as the warm-up's ``jnp.zeros`` are, so jit keys the same
+        executables."""
+        sent = 0
+        out = []
+        for name in names:
+            dev = self._on_device.get(name)
+            if dev is None:
+                host = getattr(self, name)
+                dev = self._on_device[name] = jnp.asarray(host)
+                sent += host.nbytes
+            out.append(dev)
+        return out, sent
+
     def _attend(self, q: np.ndarray, tables: np.ndarray,
                 seq_lens: np.ndarray) -> np.ndarray:
         """q: [rows, H, D] -> logits [rows, vocab], via the paged kernel.
@@ -125,14 +150,16 @@ class JaxBackend(PagedSurrogateBackend):
                 ks[:, :len(used)] = self.k_scales[:, used]
                 vs[:, :len(used)] = self.v_scales[:, used]
                 host_scales = dict(k_scales=ks, v_scales=vs)
-        host = (qp, kc, vc, bt, sl, self._wo)
+        host = (qp, kc, vc, bt, sl)
         with self._span("upload") as sp:
             scales = {k: jnp.asarray(a) for k, a in host_scales.items()}
             args = [jnp.asarray(a) for a in host]
+            (wo,), sent = self._resident("_wo")
             if sp is not None:
-                sp.n = sum(a.nbytes for a in (*host, *host_scales.values()))
+                sp.n = sent + sum(a.nbytes for a in
+                                  (*host, *host_scales.values()))
         with self._span("device_wait"):
-            logits = np.asarray(attend_logits(*args, **scales))
+            logits = np.asarray(attend_logits(*args, wo, **scales))
         return logits[:rows]
 
     # -- fused multi-step decode (docs/multi_step.md) -------------------
@@ -193,12 +220,11 @@ class JaxBackend(PagedSurrogateBackend):
         kc[:, :len(used)] = self.k_pages[:, used]
         vc[:, :len(used)] = self.v_pages[:, used]
 
+        weights, _ = self._resident("_embed", "_wq", "_wk", "_wv", "_wo")
         kc_o, vc_o, toks, emits = decode_scan(
             jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(bt),
             jnp.asarray(sl0), jnp.asarray(tok0), jnp.asarray(bud),
-            jnp.asarray(eos_v), jnp.asarray(self._embed),
-            jnp.asarray(self._wq), jnp.asarray(self._wk),
-            jnp.asarray(self._wv), jnp.asarray(self._wo), n_steps=k)
+            jnp.asarray(eos_v), *weights, n_steps=k)
         self.k_pages[:, used] = np.asarray(kc_o)[:, :len(used)]
         self.v_pages[:, used] = np.asarray(vc_o)[:, :len(used)]
         toks = np.asarray(toks)

@@ -9,14 +9,19 @@ reference and the make_backend registry.
 """
 from __future__ import annotations
 
+import dataclasses
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.backend import EmulatedBackend, StepResult, make_backend
+from repro.backend import (EmulatedBackend, StepResult, jax_backend,
+                           make_backend)
+from repro.backend.cpu_decode import CpuDecodeBackend
 from repro.backend.jax_backend import JaxBackend
 from repro.core.devmodel import DeviceModel
 from repro.serving.request import Request, RequestState
-from repro.serving.scheduler import Scheduler, SchedulerConfig
+from repro.serving.scheduler import Scheduler, SchedulerConfig, StepPlan
 
 BLOCK, NBLOCKS = 8, 64
 SCHED_CFG = SchedulerConfig(
@@ -25,8 +30,10 @@ SCHED_CFG = SchedulerConfig(
     kv_capacity_tokens=NBLOCKS * BLOCK)
 
 
-def _workload():
-    specs = [(21, 3, 1), (40, 5, 2), (21, 2, 1), (9, 4, 3)]
+SPECS = [(21, 3, 1), (40, 5, 2), (21, 2, 1), (9, 4, 3)]
+
+
+def _workload(specs=SPECS):
     reqs = []
     for n, max_new, stream in specs:
         r = Request(text="", max_new_tokens=max_new)
@@ -36,11 +43,12 @@ def _workload():
     return reqs
 
 
-def _drive(backend, max_steps: int = 500):
+def _drive(backend, max_steps: int = 500, cfg: SchedulerConfig = SCHED_CFG,
+           specs=SPECS):
     """Run the workload to completion; returns (completion order, counts,
     sampled tokens per request)."""
-    sched = Scheduler(SCHED_CFG)
-    reqs = _workload()
+    sched = Scheduler(cfg)
+    reqs = _workload(specs)
     for r in reqs:
         sched.add_request(r)
     idx_of = {r.req_id: i for i, r in enumerate(reqs)}   # workload position
@@ -81,6 +89,85 @@ def test_jax_backend_is_deterministic():
     _, _, b = _drive(JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS,
                                 vocab=128))
     assert a == b
+
+
+WEIGHTS = ("_embed", "_wq", "_wk", "_wv", "_wo")
+
+
+@pytest.mark.parametrize("k", (1, 4))
+def test_jax_backend_hands_each_weight_to_the_device_once(monkeypatch, k):
+    """The weights are final after construction: the single-step path
+    (k=1) hands the projection to the device in its first step only, the
+    multi-step path (k=4) each of its weights once over all its
+    macro-plans; the tokens stay the CPU backend's."""
+    handed, scans = [], []
+
+    class CountingJnp:           # jax.numpy, recording what goes over
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        def asarray(self, a, *args, **kw):
+            handed.append(a)
+            return jnp.asarray(a, *args, **kw)
+
+    def decode_scan(*args, **kw):
+        scans.append(kw["n_steps"])
+        return real_scan(*args, **kw)
+
+    real_scan = jax_backend.decode_scan
+    monkeypatch.setattr(jax_backend, "jnp", CountingJnp())
+    monkeypatch.setattr(jax_backend, "decode_scan", decode_scan)
+    cfg = dataclasses.replace(SCHED_CFG, max_steps_per_dispatch=k)
+    specs = [(21, 13, 1), (40, 11, 2)]
+    be = JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128)
+    _, _, tokens = _drive(be, cfg=cfg, specs=specs)
+    monkeypatch.undo()
+    handed_once = {name: sum(a is getattr(be, name) for a in handed)
+                   for name in WEIGHTS}
+    if k == 1:
+        assert not scans
+        assert handed_once == {**dict.fromkeys(WEIGHTS, 0), "_wo": 1}
+    else:
+        assert len(scans) >= 2, "the workload must run several macro-plans"
+        assert handed_once == dict.fromkeys(WEIGHTS, 1)
+    _, _, ref = _drive(CpuDecodeBackend(block_size=BLOCK,
+                                        num_blocks=NBLOCKS, vocab=128),
+                       cfg=cfg, specs=specs)
+    assert tokens == ref
+
+
+def test_resident_projection_reuses_the_warmed_executable(monkeypatch):
+    """A worker warms ``attend_logits`` with uncommitted ``jnp.zeros`` at
+    each bucket; a step at a warmed bucket, the resident projection
+    included, finds that executable and compiles nothing."""
+    plans = [StepPlan(1, [(1, 0, 12)], [], [], block_tables={1: [0, 1]},
+                      new_tokens={1: list(range(12))}, prefill_done=[1]),
+             StepPlan(2, [], [1], [], block_tables={1: [0, 1]},
+                      new_tokens={1: [5]})]
+    shapes = []
+    real = jax_backend.attend_logits
+
+    def attend_logits(*args, **kw):
+        shapes.append([a.shape for a in args])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(jax_backend, "attend_logits", attend_logits)
+    be = JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128)
+    for plan in plans:
+        be.execute(plan)
+    monkeypatch.undo()
+    assert shapes[0] == shapes[1], "both steps must share one bucket"
+    q, kc, _, bt, sl, wo = shapes[0]
+    real.clear_cache()
+    zeros = jnp.zeros(kc, jnp.float32)
+    real(jnp.zeros(q, jnp.float32), zeros, zeros,
+         jnp.full(bt, -1, jnp.int32), jnp.zeros(sl, jnp.int32),
+         jnp.zeros(wo, jnp.float32)).block_until_ready()
+    warmed = real._cache_size()
+    be = JaxBackend(block_size=BLOCK, num_blocks=NBLOCKS, vocab=128)
+    for plan in plans:      # the first makes the resident copy
+        be.execute(plan)
+    assert real._cache_size() == warmed
 
 
 def test_make_backend_registry():

@@ -86,20 +86,24 @@ def test_upload_counts_the_bytes_handed_to_the_device(monkeypatch):
     sent = []
 
     def asarray(a, *args, **kw):
-        sent.append(a.nbytes)
+        sent.append((a.shape, a.nbytes))
         return jnp.asarray(a, *args, **kw)
 
     monkeypatch.setattr(jax_backend, "jnp",
                         SimpleNamespace(asarray=asarray))
     prof = profiling.Profiler(ProfilingConfig(trace=True), role="w")
     steps = _drive(prof)
-    # float32 pool: q, K pages, V pages, block table, lengths, projection
-    assert len(sent) == 6 * len(steps)
-    per_step = [sum(sent[i:i + 6]) for i in range(0, len(sent), 6)]
+    # float32 pool: every step hands over q, K pages, V pages, block
+    # table, lengths; the first also the projection, kept from then on
+    assert len(sent) == 1 + 5 * len(steps)
+    per_step = [sent[:6]] + [sent[i:i + 5] for i in range(6, len(sent), 5)]
     ups = [ev for ev in prof.events if ev.site == "upload"]
-    assert [ev.n for ev in ups] == per_step
-    # the projection alone is E x vocab float32s
-    assert all(n > 64 * 128 * 4 for n in per_step)
+    assert [ev.n for ev in ups] == [sum(n for _, n in s) for s in per_step]
+    # the projection is E x vocab float32s, in the first step only
+    proj = ((64, 128), 64 * 128 * 4)
+    assert per_step[0][-1] == proj
+    assert all(proj not in s for s in per_step[1:])
+    assert all(ev.n < proj[1] for ev in ups[1:])
     assert all(ev.n is None for ev in prof.events if ev.site != "upload")
 
 
