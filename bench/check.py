@@ -7,13 +7,14 @@ number is at or under its limit:
   requests drawn from the seed (the longest among them), every token the
   workers' backends sampled for them (each served token, and the sample
   at the end of each earlier prefill chunk, which the engine drops), set
-  against the plain reference (``bench.reference``) run over the same
-  input tokens: the gap by which the sampled token's reference logit
-  lies below the reference's best, in units of the row's standard
-  deviation; the widest, and the mean over the sampled tokens.  Greedy
-  tokens only, which is all the program samples.  Those the
-  configuration gives a limit are compared.  In a control run the
-  control's tokens stand in for the workers' (``compare``).
+  against the plain reference of the configuration's model (its module's
+  ``logits``, ``bench/models/<name>.py``) run over the same input tokens:
+  the gap by which the sampled token's reference logit lies below the
+  reference's best, in units of the row's standard deviation; the
+  widest, and the mean over the sampled tokens.  Greedy tokens only,
+  which is all the program samples.  Those the configuration gives a
+  limit are compared.  In a control run the control's tokens stand in
+  for the workers' (``compare``).
 * ``prompt_mismatch``: sampled requests whose prompt, as the worker
   received it, is not the generator's token for token.  Limit 0.
 * ``length_mismatch``: finished requests whose output count differs
@@ -24,12 +25,10 @@ number is at or under its limit:
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
-
-from bench import reference
-from bench.work import Widths
 
 MISSING = 1e9          # the gap of a token that is absent or out of range
 
@@ -55,16 +54,15 @@ def pick_sample(candidates: List[dict], seed: int,
     return out
 
 
-def compare(w: Widths, weight_seed: int, sample: List[dict],
-            dumps: Dict[int, dict], control: Optional[str] = None) -> dict:
+def compare(model: ModuleType, config: dict, seed: int,
+            sample: List[dict], dumps: Dict[int, dict],
+            control: bool = False) -> dict:
     """Readings of the sampled requests on every worker: the widest
     ``logit_gap``, ``prompt_mismatch`` and the workers' share of
-    ``length_mismatch``.  With ``control`` (a precision of
-    ``reference.CONTROL_BELOW``), the control's tokens stand in the
-    workers' place: at each sampled position, the token the reference in
-    that precision puts first, over the same input tokens."""
-    if control not in (None, "fp8_e4m3"):
-        raise ValueError(f"no control in {control!r}")
+    ``length_mismatch``, against ``model``'s reference with its weights
+    drawn from the run's ``seed``.  With ``control``, the control's tokens
+    stand in the workers' place: at each sampled position, the token the
+    model's ``logits_control`` puts first, over the same input tokens."""
     preempted = set()
     for d in dumps.values():
         preempted |= set(d["preempted"])
@@ -73,8 +71,7 @@ def compare(w: Widths, weight_seed: int, sample: List[dict],
     for r in sample:
         for d in dumps.values():
             tokens |= set(d["streams"][r["rid"]])
-    wt = reference.draw_weights(weight_seed, w, tokens)
-    wo_c = reference.fp8(wt.wo) if control else None
+    wt = model.draw_weights(seed, config, tokens)
     out = {"logit_gap": 0.0, "prompt_mismatch": 0, "length_mismatch": 0,
            "sampled_checked": 0, "skipped_preempted": len(preempted)}
     total = 0.0
@@ -95,20 +92,28 @@ def compare(w: Widths, weight_seed: int, sample: List[dict],
             lens = [n for n, _ in samples]
             if key != (stream, lens):
                 key = (stream, lens)
-                ref = reference.logits(w, wt, stream, lens)
+                ref = model.logits(config, wt, stream, lens)
                 if control:
-                    ctl = reference.logits_fp8(w, wt, stream, lens,
-                                               wo_c).argmax(axis=1)
+                    ctl = model.logits_control(config, wt, stream,
+                                               lens).argmax(axis=1)
             toks = (ctl if control else
                     np.array([-1 if t is None else t for _, t in samples]))
-            bad = (toks < 0) | (toks >= w.vocab)
-            g = reference.gaps(ref, np.where(bad, 0, toks))
+            bad = (toks < 0) | (toks >= ref.shape[1])
+            g = gaps(ref, np.where(bad, 0, toks))
             g[bad] = MISSING
             out["logit_gap"] = max(out["logit_gap"], float(g.max()))
             out["sampled_checked"] += len(toks)
             total += float(g.sum())
     out["logit_gap_mean"] = total / max(1, out["sampled_checked"])
     return out
+
+
+def gaps(ref: np.ndarray, tokens: np.ndarray) -> np.ndarray:
+    """How far each chosen token's reference logit lies below the row's
+    best, in units of the row's standard deviation."""
+    rows = np.arange(len(tokens))
+    best = ref.max(axis=1)
+    return (best - ref[rows, tokens]) / ref.std(axis=1)
 
 
 def verdict(numbers: Dict[str, tuple]) -> bool:

@@ -8,7 +8,9 @@ with its defaults, every host core given, and drives it through
 it) and builds its backend through ``bench.worker`` (see there).
 
 Everything a cell needs is found by name: its entry in
-``BENCHMARK.json``, ``bench/configs/<config>.json``,
+``BENCHMARK.json``, ``bench/configs/<config>.json``, the served model
+that configuration names, ``bench/models/<model>.py`` (all the harness
+knows of the model: see ``bench/models/surrogate.py``),
 ``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py`` (or,
 for ``<base>.<suffix>``, ``bench/metrics/<base>.py``).
 """
@@ -23,6 +25,7 @@ import sys
 import threading
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -40,6 +43,7 @@ class Cell:
     name: str
     chips: int
     config: dict                # bench/configs/<config>.json
+    model: ModuleType           # bench/models/<config["model"]>.py
     traffic: dict               # bench/traffic/<traffic>.json
     metrics: List[dict]         # BENCHMARK.json metric entries to report
 
@@ -53,13 +57,40 @@ def load_cell(bench_file: Path, workload: str, trace: bool) -> Cell:
     w = cells[workload]
     conf = next(c for c in bench["configs"] if c["name"] == w["config"])
     config = json.loads((ROOT / conf["file"]).read_text())
+    model = load_model(ROOT / bench.get("models_dir", "bench/models"),
+                       config, conf["file"])
     traffic_dir = ROOT / bench.get("traffic_dir", "bench/traffic")
     traffic = json.loads(
         (traffic_dir / f"{w['traffic']}.json").read_text())
     listed = bench["per_layer"] if trace else bench["end_to_end"]
     metrics = [m for m in listed
                if workload in m.get("workloads", [workload])]
-    return Cell(workload, w["chips"], config, traffic, metrics)
+    return Cell(workload, w["chips"], config, model, traffic, metrics)
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    """The module at ``path``, as ``name`` (registered first, as a module
+    defining dataclasses needs)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(models_dir: Path, config: dict, where: str) -> ModuleType:
+    """The module of the served model the configuration names
+    (``"model": "<name>"``): ``<models_dir>/<name>.py``.  A configuration
+    that names none, or one with no module, is refused: there is no
+    default model."""
+    name = config.get("model")
+    if not isinstance(name, str) or not name:
+        raise SystemExit(f"{where}: no \"model\"; name the served model "
+                         f"(a module in {models_dir})")
+    path = models_dir / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"{where}: no module {path} for model {name!r}")
+    return _load(path, f"bench_model_{name}")
 
 
 def metric_reader(name: str):
@@ -68,11 +99,7 @@ def metric_reader(name: str):
     path = BENCH / "metrics" / f"{name}.py"
     if not path.exists():
         path = BENCH / "metrics" / f"{name.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"bench_metric_{name.replace('.', '_')}").read
 
 
 @dataclasses.dataclass
@@ -87,9 +114,16 @@ class Run:
     workers: Dict[int, dict]               # worker dumps, by index
     traces: Dict[int, dict]                # traced run: per worker
     spans: list                            # (role, SpanEvent), traced
-    widths: object
     peak: Optional[dict]
     chips: int
+
+    @property
+    def model(self) -> ModuleType:
+        return self.cell.model
+
+    @property
+    def config(self) -> dict:
+        return self.cell.config
 
     def in_window(self, t: float) -> bool:
         return self.window[0] <= t < self.window[1]
@@ -120,14 +154,6 @@ def engine_config(config: dict, trace: bool):
         model=get_config(config["arch"]).scaled(
             **config.get("arch_overrides", {})), yield_every=64,
         profiling=ProfilingConfig(trace=trace))
-
-
-def widths_of(config: dict):
-    from bench.work import Widths
-    return Widths(n_heads=config["num_attention_heads"],
-                  n_kv_heads=config["num_key_value_heads"],
-                  head_dim=config["head_dim"],
-                  vocab=config["vocab_size"])
 
 
 def warm_spec(traffic: dict, sched) -> dict:
@@ -295,7 +321,7 @@ def _run(cell: Cell, args, t_start: float, allow_cpu: bool) -> tuple:
     from repro.profiling import events_from_stats
     from repro.tokenizer.bpe import default_tokenizer
 
-    from bench import check, loadgen, reference, worker
+    from bench import check, loadgen, worker
     from bench.work import PEAKS, peak
 
     config, spec = cell.config, cell.traffic
@@ -305,11 +331,10 @@ def _run(cell: Cell, args, t_start: float, allow_cpu: bool) -> tuple:
     if cfg.tp_degree != cell.chips:
         raise ValueError(f"{cell.name}: tp {cfg.tp_degree} on "
                          f"{cell.chips} chips")
-    widths = widths_of(config)
     run_dir = RUNS / f"{cell.name}.{args.seed}"
     channel = worker.Channel(cfg.tp_degree)
     wspec = worker.WorkerSpec(
-        seed=worker.weight_seed(args.seed), widths=widths,
+        seed=args.seed, model=cell.model, config=config,
         warm=warm_spec(spec, cfg.scheduler), channel=channel,
         trace_dir=str(run_dir / "trace") if args.trace else None)
     worker.install(wspec)
@@ -357,9 +382,10 @@ def _run(cell: Cell, args, t_start: float, allow_cpu: bool) -> tuple:
         raise RuntimeError("the engine sent multi-step or speculative "
                            "plans, which the check does not follow")
 
-    control = (reference.control_below(config["torch_dtype"])
-               if args.control else None)
-    readings = check.compare(widths, wspec.seed, sample, dumps, control)
+    control = (cell.model.control_precision(config) if args.control
+               else None)
+    readings = check.compare(cell.model, config, args.seed, sample, dumps,
+                             bool(args.control))
     numbers = {k: (readings[k], lim) for k, lim in config["limits"].items()}
     numbers.update({
         "prompt_mismatch": (readings["prompt_mismatch"], 0),
@@ -371,7 +397,6 @@ def _run(cell: Cell, args, t_start: float, allow_cpu: bool) -> tuple:
     run = Run(cell=cell, setup_s=setup_s,
               window=window, t_end=t_end, requests=requests, workers=dumps,
               traces=traces, spans=events_from_stats(stats),
-              widths=widths,
               peak=PEAKS.get(kind) if allow_cpu else peak(kind),
               chips=cell.chips)
     metrics = {}
@@ -383,7 +408,7 @@ def _run(cell: Cell, args, t_start: float, allow_cpu: bool) -> tuple:
     device = {"platform": next(iter(kinds)), "kind": kind,
               "count": cell.chips,
               "memory_peak_bytes": max((p for p in peaks if p), default=0)}
-    attempted = [r for r in requests if r["in_window"]]
+    attempted = window_attempts(requests, window, spec["loop"])
     line = {"correct": check.verdict(numbers),
             "attempted": len(attempted),
             # open loop: a window request that never finished failed;
@@ -447,7 +472,7 @@ def _sweep(cell: Cell, args, rates: List[float], allow_cpu: bool) -> None:
     cfg = engine_config(cell.config, False)
     channel = worker.Channel(cfg.tp_degree)
     worker.install(worker.WorkerSpec(
-        seed=worker.weight_seed(args.seed), widths=widths_of(cell.config),
+        seed=args.seed, model=cell.model, config=cell.config,
         warm=warm_spec(cell.traffic, cfg.scheduler), channel=channel))
     sys_ = ServingSystem(cfg, tokenizer=tok).start()
     try:
@@ -464,8 +489,8 @@ def _sweep(cell: Cell, args, rates: List[float], allow_cpu: bool) -> None:
             requests = _records(traffic, sent, due, sys_.results, window)
             run = Run(cell=cell, setup_s=0.0, window=window,
                       t_end=time.perf_counter(), requests=requests,
-                      workers={}, traces={}, spans=[], widths=None,
-                      peak=None, chips=cell.chips)
+                      workers={}, traces={}, spans=[], peak=None,
+                      chips=cell.chips)
             line = {"rate_per_s": rate, "seed": args.seed + k,
                     "attempted": sum(r["in_window"] for r in requests)}
             line.update(_latencies(run))
@@ -488,6 +513,21 @@ def _records(traffic, sent, due, results, window) -> List[dict]:
                     "max_new": r.max_new, "result": results.get(rid),
                     "in_window": window[0] <= due[r.idx] < window[1]})
     return out
+
+
+def window_attempts(requests: List[dict], window: tuple,
+                    loop: str) -> List[dict]:
+    """The requests a run attempted in its window.  Open loop: those due
+    in it.  Closed loop: every request sent before it closed that had not
+    finished before it opened; a send waits for a finish, so at some
+    seeds no request is sent inside the window at all.  A timed-out
+    request has no finish (the engine leaves its ``t_done`` at 0): one
+    sent before the close counts, as failed."""
+    if loop == "open":
+        return [r for r in requests if r["in_window"]]
+    return [r for r in requests if r["due"] < window[1]
+            and (r["result"] is None or r["result"].get("timed_out")
+                 or r["result"]["t_done"] >= window[0])]
 
 
 def _stop(sys_, timeout: float) -> list:

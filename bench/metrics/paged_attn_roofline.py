@@ -1,18 +1,16 @@
 """Kernel ``repro.kernels.paged_decode_attention``: the least time its
 useful work could take on the chip (operations over peak or bytes over
-bandwidth, whichever is larger) over the kernel's own time in the trace;
-the mean over the cell's chips.  Useful work counts each row's cached
-tokens, not the padded block table."""
+bandwidth, whichever is larger; the counts are the configuration's model
+module's ``paged_attn_flops`` and ``paged_attn_bytes``) over the
+kernel's own time in the trace; the mean over the cell's chips.  Useful
+work counts each row's cached tokens, not the padded block table."""
 from bench import stats
-from bench.work import least_time, paged_attn_bytes, paged_attn_flops
-
+from bench.work import least_time
 
 
 def is_kernel(op: str) -> bool:
-    """The kernel's events in the trace: a Pallas call named for it, or,
-    unnamed, the one Pallas call of the served step ``attend_logits``."""
-    return ("paged_decode_attention" in op
-            or (op.startswith("%attend_logits") and "tpu_custom_call" in op))
+    """The kernel's events in the trace: the Pallas call named for it."""
+    return "paged_decode_attention" in op
 
 
 def read(run):
@@ -21,8 +19,9 @@ def read(run):
     shares = []
     for idx, tr in run.traces.items():
         work = stats.window_work(run, idx)
-        t_min, _ = least_time(paged_attn_flops(run.widths, work),
-                              paged_attn_bytes(run.widths, work), run.peak)
+        t_min, _ = least_time(run.model.paged_attn_flops(run.config, work),
+                              run.model.paged_attn_bytes(run.config, work),
+                              run.peak)
         for dev in tr.values():
             ns = sum(v for k, v in dev["op_ns"].items() if is_kernel(k))
             if ns and t_min:

@@ -1,8 +1,7 @@
 """Whole step: the served model's operations for the tokens the window
-processed (counted from the model's shapes, once however many chips
-replicate it), over window x chips x the chip's peak."""
+processed (counted by the configuration's model module, once however
+many chips replicate it), over window x chips x the chip's peak."""
 from bench import stats
-from bench.work import model_flops
 
 
 def read(run):
@@ -12,5 +11,5 @@ def read(run):
     if work.rows == 0:
         return None
     span = run.window[1] - run.window[0]
-    return 100.0 * model_flops(run.widths, work) / (
+    return 100.0 * run.model.model_flops(run.config, work) / (
         span * run.chips * run.peak["flops_per_s"])

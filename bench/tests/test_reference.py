@@ -1,16 +1,21 @@
-"""The plain reference: the program's weights from the seed alone, and a
-control (the reference one precision below the configuration's) that
-reads above the limit."""
+"""The surrogate's plain reference (``bench/models/surrogate.py``): the
+program's weights from the seed alone, and a control (the reference one
+precision below the configuration's) that reads above the limit."""
 import json
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from bench import reference
-from bench.work import Widths
+from bench import check
+from bench.models import surrogate
 
 ROOT = Path(__file__).resolve().parents[2]
+
+
+def _config(n_heads, n_kv_heads, head_dim, vocab):
+    return {"num_attention_heads": n_heads, "num_key_value_heads": n_kv_heads,
+            "head_dim": head_dim, "vocab_size": vocab}
 
 
 def test_weights_are_the_programs():
@@ -21,8 +26,8 @@ def test_weights_are_the_programs():
     prog = PagedSurrogateBackend(block_size=16, num_blocks=4, n_heads=4,
                                  n_kv_heads=2, head_dim=16, vocab=300,
                                  seed=seed)
-    w = Widths(n_heads=4, n_kv_heads=2, head_dim=16, vocab=300)
-    wt = reference.draw_weights(seed, w, [0, 5, 299, 301])
+    cfg = _config(n_heads=4, n_kv_heads=2, head_dim=16, vocab=300)
+    wt = surrogate.draw_weights(seed, cfg, [0, 5, 299, 301])
     for name in ("wq", "wk", "wv", "wo"):
         np.testing.assert_array_equal(getattr(wt, name),
                                       getattr(prog, "_" + name))
@@ -33,10 +38,10 @@ def test_weights_are_the_programs():
 
 def test_reference_logits_match_a_plain_loop():
     """The vectorised reference against a loop over heads and tokens."""
-    w = Widths(n_heads=4, n_kv_heads=2, head_dim=8, vocab=50)
+    cfg = _config(n_heads=4, n_kv_heads=2, head_dim=8, vocab=50)
     stream = [3, 7, 1, 0, 0, 9]
-    wt = reference.draw_weights(5, w, stream)
-    got = reference.logits(w, wt, stream, [4, 6])
+    wt = surrogate.draw_weights(5, cfg, stream)
+    got = surrogate.logits(cfg, wt, stream, [4, 6])
     e = np.stack([wt.embed[wt.rows[t]] for t in stream]).astype(np.float64)
     for p, L in enumerate([4, 6]):
         q = (e[L - 1] @ wt.wq).reshape(4, 8)
@@ -52,7 +57,7 @@ def test_reference_logits_match_a_plain_loop():
 
 
 def test_fp8_rounds_to_nearest_even():
-    f = reference.fp8
+    f = surrogate.fp8
     assert f(np.float32(1.0625)) == 1.0                    # tie: to even
     assert f(np.float32(1.1875)) == 1.25                   # tie: to even
     assert f(np.float32(1.07)) == 1.125
@@ -76,25 +81,23 @@ def test_control_fails_the_limit(config):
     cfg = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
                      .read_text())
     vocab, prompts, n_out = CASES[config]
-    w = Widths(cfg["num_attention_heads"], cfg["num_key_value_heads"],
-               cfg["head_dim"], vocab or cfg["vocab_size"])
-    assert reference.control_below(cfg["torch_dtype"]) == "fp8_e4m3"
+    cfg["vocab_size"] = vocab or cfg["vocab_size"]
+    assert surrogate.control_precision(cfg) == "fp8_e4m3"
     limit = cfg["limits"]["logit_gap"]
     rng = np.random.default_rng(3)
     streams = [list(rng.integers(3, 551, size=n)) + [0] * n_out
                for n in prompts]
-    wt = reference.draw_weights(77, w, range(551))
-    wo_c = reference.fp8(wt.wo)
+    wt = surrogate.draw_weights(77, cfg, range(551))
     worst = 0.0
     for s in streams:
         lens = list(range(len(s) - n_out, len(s) + 1))
-        ref = reference.logits(w, wt, s, lens)
-        assert reference.gaps(ref, ref.argmax(axis=1)).max() == 0.0
-        ctl = reference.logits_fp8(w, wt, s, lens, wo_c)
-        worst = max(worst, reference.gaps(ref, ctl.argmax(axis=1)).max())
+        ref = surrogate.logits(cfg, wt, s, lens)
+        assert check.gaps(ref, ref.argmax(axis=1)).max() == 0.0
+        ctl = surrogate.logits_control(cfg, wt, s, lens)
+        worst = max(worst, check.gaps(ref, ctl.argmax(axis=1)).max())
     assert worst > limit
 
 
 def test_no_control_below_float32():
     with pytest.raises(ValueError):
-        reference.control_below("float32")
+        surrogate.control_precision({"torch_dtype": "float32"})

@@ -48,6 +48,23 @@ FAULTS = {
         "        return StepResult(step_id=plan.step_id)\n"
         "    return _x(self, plan, block_tables)\n"
         "J.execute = _skip\n"),
+    # not a fault: a client that gives up, every fourth request timing
+    # out before its first token (the first three, whose first tokens
+    # open a closed loop's window, never do)
+    "some_time_out": (
+        "from repro.serving.scheduler import Scheduler as S\n"
+        "S._expired = lambda self, req, now, timeout: (\n"
+        "    not req.t_first_token and req.req_id % 4 == 3)\n"),
+    # not a fault: every step at least 60 ms, so a closed loop's first
+    # requests (48-64 tokens) all outlast a 2 s window
+    "slow_steps": (
+        "import time\n"
+        "from repro.backend.surrogate import PagedSurrogateBackend as P\n"
+        "_x = P.execute\n"
+        "def _slow(self, plan, block_tables=None):\n"
+        "    time.sleep(0.06)\n"
+        "    return _x(self, plan, block_tables)\n"
+        "P.execute = _slow\n"),
 }
 
 DRIVER = """
@@ -63,9 +80,10 @@ sys.exit(main({argv!r}, t_start=T0, bench_file=Path({bench!r}),
 
 
 def run_cell(workload: str, fault: str, seed: int = 2**31 + 5,
-             trace: int = 0, control: int = 0, seconds: int = 2) -> dict:
+             trace: int = 0, control: int = 0, seconds: int = 2,
+             bench: Path = DATA / "bench.json") -> dict:
     code = DRIVER.format(root=str(ROOT), src=str(ROOT / "src"),
-                         fault=FAULTS[fault], bench=str(DATA / "bench.json"),
+                         fault=FAULTS[fault], bench=str(bench),
                          argv=["--workload", workload, "--seed", str(seed),
                                "--seconds", str(seconds),
                                "--trace", str(trace),
@@ -113,6 +131,61 @@ def test_control_is_not_correct():
     assert line["readings"]["control"] == "fp8_e4m3"
     assert (line["check"]["logit_gap"]["value"]
             > line["check"]["logit_gap"]["limit"])
+
+
+def test_closed_loop_counts_the_requests_in_flight():
+    """Every first request outlasts the window, so none is sent inside it:
+    the window still attempted the three in flight."""
+    line = run_cell("tiny-long", "slow_steps")
+    assert line["correct"] is True
+    assert line["attempted"] == 3 and line["failed"] == 0
+
+
+def test_closed_loop_counts_its_timed_out_requests_as_failed():
+    """A request sent before the window closed that timed out (the engine
+    leaves its ``t_done`` at 0) is attempted, and failed."""
+    line = run_cell("tiny-closed", "some_time_out")
+    assert line["correct"] is True
+    assert line["failed"] >= 1 and line["attempted"] > line["failed"]
+
+
+def _bench_naming(tmp_path: Path, model) -> Path:
+    """The tests' ``bench.json`` with the ``tiny`` configuration naming
+    ``model`` (none where None), its models found in ``data/models``."""
+    bench = json.loads((DATA / "bench.json").read_text())
+    config = json.loads((DATA / "tiny.json").read_text())
+    del config["model"]
+    if model is not None:
+        config["model"] = model
+    (tmp_path / "tiny.json").write_text(json.dumps(config))
+    for c in bench["configs"]:
+        if c["name"] == "tiny":
+            c["file"] = str(tmp_path / "tiny.json")
+    bench["models_dir"] = str(DATA / "models")
+    (tmp_path / "bench.json").write_text(json.dumps(bench))
+    return tmp_path / "bench.json"
+
+
+def test_the_check_follows_the_configurations_model(tmp_path):
+    """A configuration that names a model module whose reference negates
+    the output projection reads not correct on a sound program."""
+    line = run_cell("tiny-open", "none",
+                    bench=_bench_naming(tmp_path, "negated"))
+    assert line["correct"] is False
+    assert (line["check"]["logit_gap"]["value"]
+            > line["check"]["logit_gap"]["limit"])
+
+
+@pytest.mark.parametrize("model", [None, "absent"])
+def test_a_configuration_without_its_model_is_refused(tmp_path, model):
+    """No ``"model"``, or one with no module: refused before any engine
+    or worker starts (``load_cell`` is the first thing a run does)."""
+    from bench import harness
+    with pytest.raises(SystemExit, match="model"):
+        harness.main(["--workload", "tiny-open", "--seed", "1",
+                      "--seconds", "2"],
+                     bench_file=_bench_naming(tmp_path, model),
+                     allow_cpu=True)
 
 
 def _cli(cwd: Path, workload: str) -> subprocess.CompletedProcess:

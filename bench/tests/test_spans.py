@@ -19,8 +19,8 @@ def _run(events, results=()):
     requests = [{"in_window": True, "result": r} for r in results]
     return harness.Run(cell=None, setup_s=0.0, window=(10.0, 20.0),
                        t_end=20.0, requests=requests, workers={},
-                       traces={}, spans=list(events), widths=None,
-                       peak=None, chips=1)
+                       traces={}, spans=list(events), peak=None,
+                       chips=1)
 
 
 def _step(role, step, t0, dur, upload_bytes):

@@ -1,20 +1,23 @@
-"""Work counts and peaks, against hand counts."""
+"""Work counts (the surrogate's, ``bench/models/surrogate.py``) and
+peaks, against hand counts."""
 import pytest
 
 from bench import work
-from bench.work import StepWork, Widths
+from bench.models import surrogate
+from bench.work import StepWork
 
-Q05 = Widths(n_heads=14, n_kv_heads=2, head_dim=64, vocab=151936)
+Q05 = {"num_attention_heads": 14, "num_key_value_heads": 2, "head_dim": 64,
+       "vocab_size": 151936}
 
 
 def test_paged_attn_counts_by_hand():
     # two rows attending 100 and 28 cached tokens
     wk = StepWork(tokens_written=0, rows=2, ctx=128)
     # q.k and p.v: 2 matmuls x 2 ops x 14 heads x 64 x 128 tokens
-    assert work.paged_attn_flops(Q05, wk) == 2 * 2 * 14 * 64 * 128
+    assert surrogate.paged_attn_flops(Q05, wk) == 2 * 2 * 14 * 64 * 128
     # K and V: 2 x 2 kv heads x 64 x 4 B x 128 tokens; q and out: 2 rows
     # x 2 x 14 x 64 x 4 B
-    assert work.paged_attn_bytes(Q05, wk) == (2 * 2 * 64 * 4 * 128
+    assert surrogate.paged_attn_bytes(Q05, wk) == (2 * 2 * 64 * 4 * 128
                                               + 2 * 2 * 14 * 64 * 4)
 
 
@@ -25,7 +28,7 @@ def test_model_flops_by_hand():
               + 2 * e * e                      # query projection
               + 2 * e * 151936                 # logits
               + 4 * 14 * 64 * 10)              # attention
-    assert work.model_flops(Q05, wk) == expect
+    assert surrogate.model_flops(Q05, wk) == expect
 
 
 def test_step_work_adds():

@@ -1,5 +1,6 @@
-"""The warm-up's shape buckets."""
-from bench.worker import pow2_at_least, warm_buckets
+"""The surrogate's warm-up shape buckets."""
+from bench.models.surrogate import (draw_weights, pow2_at_least, seeded,
+                                    warm_buckets, warm_up)
 
 
 def _bucket(rows, pages, used):
@@ -24,3 +25,36 @@ def test_buckets_respect_the_bounds():
     assert {r for r, _, _ in got} == {2, 4, 8, 16}
     assert {nb for _, nb, _ in got} == {32, 64, 128, 256}
     assert all(max(r, nb) <= p <= r * nb for r, nb, p in got)
+
+
+def test_a_backend_with_its_own_warm_up_is_asked():
+    """A backend that has ``warm_up`` compiles its own shapes: the module
+    passes the bounds and reports its count."""
+    class Backend:
+        def warm_up(self, **bounds):
+            self.bounds = bounds
+            return 7
+
+    b, bounds = Backend(), {"max_rows": 4, "min_pages": 1, "max_pages": 2,
+                            "num_blocks": 8}
+    assert warm_up(b, bounds) == {"warm_shapes": 7}
+    assert b.bounds == bounds
+
+
+def test_seeded_backend_draws_the_runs_weights():
+    """``seeded`` hands the run's seed (past 32 bits) to the program's
+    backend, whose weights then equal the reference's draw; the
+    constructor is restored afterwards."""
+    import numpy as np
+    from repro.backend.surrogate import PagedSurrogateBackend as P
+    init = P.__init__
+    seed = 2**33 + 17
+    b = seeded(lambda name, **kw: P(**kw), seed, "cpu",
+               dict(block_size=16, num_blocks=4, n_heads=4, n_kv_heads=2,
+                    head_dim=16, vocab=300))
+    assert P.__init__ is init
+    cfg = {"num_attention_heads": 4, "num_key_value_heads": 2,
+           "head_dim": 16, "vocab_size": 300}
+    wt = draw_weights(seed, cfg, [3])
+    np.testing.assert_array_equal(wt.wo, b._wo)
+    np.testing.assert_array_equal(wt.embed[wt.rows[3]], b._embed[3])

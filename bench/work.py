@@ -1,12 +1,6 @@
-"""Work counts from shapes, and the chip's published peaks.
-
-The served model is the program's paged surrogate: per token it writes
-K and V (projections of the token's embedding), and per sampled row it
-projects a query, attends over the row's cached tokens and projects the
-attention output to logits over the vocabulary.  Counts are of the
-useful work of that model, two operations per multiply-add, whatever
-runs it and wherever: padding to buckets is not counted.
-"""
+"""The chip's published peaks, what a step processed, and the least time
+work could take at those peaks.  The counts of a model's operations and
+bytes live with the model (``bench/models/<name>.py``)."""
 from __future__ import annotations
 
 import dataclasses
@@ -30,19 +24,6 @@ def peak(device_kind: str) -> dict:
                        f"{device_kind!r}; add them to bench/work.py") from None
 
 
-@dataclasses.dataclass(frozen=True)
-class Widths:
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    vocab: int
-    dtype_bytes: int = 4          # the KV pool and the projections: f32
-
-    @property
-    def embed(self) -> int:
-        return self.n_heads * self.head_dim
-
-
 @dataclasses.dataclass
 class StepWork:
     """What one step (or a sum of steps) processed.
@@ -60,30 +41,6 @@ class StepWork:
         self.rows += other.rows
         self.ctx += other.ctx
         return self
-
-
-def paged_attn_flops(w: Widths, work: StepWork) -> float:
-    """q.k and p.v over every attended token, for every query head."""
-    return 4.0 * w.n_heads * w.head_dim * work.ctx
-
-
-def paged_attn_bytes(w: Widths, work: StepWork) -> float:
-    """K and V of every attended token, read once per kv head, plus each
-    row's query read and output written."""
-    kv = 2.0 * w.n_kv_heads * w.head_dim * w.dtype_bytes * work.ctx
-    q_out = 2.0 * w.n_heads * w.head_dim * w.dtype_bytes * work.rows
-    return kv + q_out
-
-
-def model_flops(w: Widths, work: StepWork) -> float:
-    """The served model's operations: K/V projections per token written;
-    query projection, attention and logits projection per sampled row."""
-    e = w.embed
-    kv_proj = 2.0 * e * 2 * w.n_kv_heads * w.head_dim
-    q_proj = 2.0 * e * w.n_heads * w.head_dim
-    logits = 2.0 * e * w.vocab
-    return (kv_proj * work.tokens_written + (q_proj + logits) * work.rows
-            + paged_attn_flops(w, work))
 
 
 def least_time(flops: float, nbytes: float, pk: dict) -> tuple:

@@ -3,12 +3,13 @@
 ``install`` replaces ``repro.backend.make_backend`` in the owner before
 ``ServingSystem.start()`` forks, so every worker builds its backend
 through ``Recorder.start``: the program's own ``make_backend``, with the
-surrogate's weights drawn from the run's seed, wrapped in a ``Recorder``
-that relies only on the Backend protocol (``execute(plan) ->
-StepResult``).  In the worker the recorder
+weights drawn from the run's seed by the configuration's model module
+(``seeded``), wrapped in a ``Recorder`` that relies only on the Backend
+protocol (``execute(plan) -> StepResult``).  In the worker the recorder
 
-* compiles (or reads from the compile cache) every shape bucket the
-  cell's traffic can reach, before the owner opens the window;
+* has the model module compile (or read from the compile cache) every
+  shape the cell's traffic can reach (``warm_up``), before the owner
+  opens the window;
 * keeps, per request, the input tokens the worker received and the
   tokens the backend sampled, for the check of outputs;
 * keeps, per step, its host-clock span and the work it did;
@@ -29,43 +30,12 @@ import queue
 import shutil
 import threading
 import time
+from types import ModuleType
 from typing import Dict, List, Optional
 
 from bench import xplane
-from bench.work import Widths
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-def pow2_at_least(n: int, lo: int = 2) -> int:
-    p = lo
-    while p < n:
-        p *= 2
-    return p
-
-
-def pow2_range(lo: int, hi: int) -> List[int]:
-    out, p = [], pow2_at_least(lo)
-    while p <= pow2_at_least(hi):
-        out.append(p)
-        p *= 2
-    return out
-
-
-def warm_buckets(max_rows: int, min_pages: int, max_pages: int,
-                 num_blocks: int) -> List[tuple]:
-    """Every (rows, pages per row, compact pool pages) bucket that a step
-    can reach when no two rows share a page: the backend pads each to a
-    power of two of at least 2.  A step's pool holds at least its rows
-    and at least its longest row's pages, and at most rows x pages and
-    the whole pool."""
-    out = []
-    for r in pow2_range(2, max_rows):
-        for nb in pow2_range(max(min_pages, 1), max_pages):
-            hi = min(r * nb, num_blocks)
-            for p in pow2_range(max(r, nb), hi):
-                out.append((r, nb, p))
-    return out
 
 
 class Channel:
@@ -108,8 +78,9 @@ class Channel:
 @dataclasses.dataclass
 class WorkerSpec:
     seed: int
-    widths: Widths
-    warm: dict                      # warm_buckets keyword arguments
+    model: ModuleType               # bench/models/<name>.py
+    config: dict                    # bench/configs/<config>.json
+    warm: dict                      # the model's warm_up bounds
     channel: Channel
     trace_dir: Optional[str] = None  # traced run: where each worker writes
 
@@ -155,11 +126,12 @@ class Recorder:
             dev = jax.devices()[0]
             spec.channel.reply.put(("device", idx, {
                 "platform": dev.platform, "device_kind": dev.device_kind}))
-            inner = _seeded(make_backend, spec.seed, name, kw)
-            w = spec.widths
+            inner = spec.model.seeded(make_backend, spec.seed, name, kw)
+            c = spec.config
             got = (inner.n_heads, inner.n_kv_heads, inner.head_dim,
                    inner.vocab)
-            want = (w.n_heads, w.n_kv_heads, w.head_dim, w.vocab)
+            want = (c["num_attention_heads"], c["num_key_value_heads"],
+                    c["head_dim"], c["vocab_size"])
             if got != want:
                 raise ValueError(f"backend widths {got} != config {want}")
             rec = cls(inner, idx, spec)
@@ -174,23 +146,11 @@ class Recorder:
 
     def _warm_up(self) -> dict:
         import jax
-        import jax.numpy as jnp
-        from repro.backend.jax_backend import attend_logits
         jax.monitoring.register_event_duration_secs_listener(
             self._on_event)
-        b = self.inner
-        shapes = warm_buckets(**self.spec.warm)
         t0 = time.perf_counter()
-        wo = jnp.zeros((b.n_heads * b.head_dim, b.vocab), jnp.float32)
-        for rows, nb, pool in shapes:
-            kc = jnp.zeros((b.n_kv_heads, pool, b.block_size, b.head_dim),
-                           jnp.float32)
-            attend_logits(
-                jnp.zeros((rows, b.n_heads, b.head_dim), jnp.float32),
-                kc, kc, jnp.full((rows, nb), -1, jnp.int32),
-                jnp.zeros((rows,), jnp.int32), wo).block_until_ready()
-        del wo
-        return {"warm_shapes": len(shapes),
+        info = self.spec.model.warm_up(self.inner, self.spec.warm)
+        return {"warm_shapes": info["warm_shapes"],
                 "warm_s": time.perf_counter() - t0,
                 "warm_compiles": len(self.compiles)}
 
@@ -320,24 +280,3 @@ class Recorder:
                     "compiles": list(self.compiles),
                     "memory_peak_bytes": stats.get("peak_bytes_in_use")}
 
-
-def _seeded(make_backend, seed: int, name: str, kw: dict):
-    """The program's ``make_backend``, with the surrogate's weights drawn
-    from ``seed`` (its constructor's ``seed``) instead of its default."""
-    from repro.backend.surrogate import PagedSurrogateBackend as P
-    original = P.__init__
-
-    def init(self, *a, **k):
-        k.setdefault("seed", seed)
-        original(self, *a, **k)
-
-    P.__init__ = init
-    try:
-        return make_backend(name, **kw)
-    finally:
-        P.__init__ = original
-
-
-def weight_seed(seed: int) -> int:
-    """The seed the surrogate's NumPy generator takes for a run seed."""
-    return seed % (1 << 64)
